@@ -30,6 +30,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 _ROW_RE = re.compile(r"^([A-Za-z0-9_.-]+),([-+0-9.eE]+)(?:,(.*))?$")
 
 
@@ -58,6 +60,7 @@ def main() -> None:
                     help="committed trajectory file to diff against; "
                          "prints a per-row delta table after the run")
     args = ap.parse_args()
+    enable_compile_cache()
 
     baseline_rows = {}
     if args.baseline:

@@ -38,6 +38,7 @@ from repro.data.pipeline import ScoreStore, SelectionStream
 from repro.serve import SelectionServer
 from repro.launch import serve as servelib
 from repro.launch import train as trainlib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model
 from repro.optim import adamw
 
@@ -74,6 +75,7 @@ def train_proxy(tokens, labels, steps=120):
 
 
 def main():
+    enable_compile_cache()
     print("[1/3] building corpus + training proxy")
     tokens, labels = synthetic.make_token_corpus(CORPUS, SEQ, CFG.vocab_size,
                                                  positive_rate=0.02, seed=1)

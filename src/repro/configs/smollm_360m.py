@@ -1,6 +1,6 @@
 """smollm-360m [dense] — llama-arch small; the cheap-proxy tier of the SUPG
 model zoo. 32L d=960 15H (kv=5) d_ff=2560 vocab=49152
-[hf:HuggingFaceTB/SmolLM-135M; hf]."""
+[hf:HuggingFaceTB/SmolLM-360M; hf]."""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(

@@ -16,9 +16,10 @@ D'), which preserves validity: stage-2 restriction is an efficiency device,
 never a correctness requirement.
 
 The per-shard sketch pass is the HBM-bandwidth hot spot and runs through the
-fused Pallas kernel (kernels/score_hist) by default whenever the bin count is
-tile-aligned — compiled on TPU, `interpret=True` emulation on CPU — with the
-pure-jnp scatter-add formulation kept as the reference/fallback path.
+fused Pallas kernel (kernels/score_hist) by default. On TPU the kernel is
+compiled and a bin count its tiling cannot take raises; off TPU it runs in
+`interpret=True` emulation, and the pure-jnp scatter-add reference takes the
+bin counts the kernel does not cover.
 
 `weight_normalizers` feeds the SelectionEngine's cached sampling state: the
 global Σ sqrt(A), Σ A and n extracted from one merged sketch are the only
@@ -33,8 +34,11 @@ from __future__ import annotations
 
 from typing import NamedTuple, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.kernels.score_hist import ops as hist_ops
 
 DEFAULT_BINS = 4096
 
@@ -63,14 +67,15 @@ def build_sketch(scores, num_bins=DEFAULT_BINS, use_kernel=None):
     """One-pass sketch of a score shard.
 
     use_kernel: True forces the fused Pallas kernel, False forces the jnp
-    scatter-add reference, None (default) auto-selects the kernel whenever
-    the bin count matches its tile layout (TPU compiled / CPU interpret).
+    scatter-add reference. None (default) picks by platform: on TPU the
+    compiled kernel, which raises ValueError for a bin count its tiling
+    cannot take; elsewhere the interpret-mode kernel where its tiling covers
+    the bin count and the jnp reference where it does not.
     """
     if use_kernel is None:
-        from repro.kernels.score_hist import ops as hist_ops
-        use_kernel = hist_ops.kernel_supported(num_bins)
+        use_kernel = (jax.default_backend() == "tpu"
+                      or hist_ops.kernel_supported(num_bins))
     if use_kernel:
-        from repro.kernels.score_hist import ops as hist_ops
         return ScoreSketch(*hist_ops.score_hist(scores, num_bins))
     scores = jnp.asarray(scores, jnp.float32)
     idx = bin_index(scores, num_bins)

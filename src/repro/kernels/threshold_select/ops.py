@@ -4,10 +4,11 @@
 pure-numpy nonzero reference elsewhere — the reference IS the CPU
 production path (interpret-mode emulation of the one-hot compaction is for
 kernel validation, not throughput, so unlike score_hist it is opt-in via
-`backend="interpret"`). `backend="ref"` forces the numpy path, which is
-also the automatic fallback whenever `block_n` is not tile-aligned. All
-backends return identical ascending int64 indices, so the streaming plane
-is backend-agnostic bit-for-bit.
+`backend="interpret"`). `backend="ref"` forces the numpy path. A kernel
+backend with a `block_n` its tiling cannot take raises instead of
+rerouting, so a caller always knows which path ran. All backends return
+identical ascending int64 indices, so the streaming plane is
+backend-agnostic bit-for-bit.
 """
 from __future__ import annotations
 
@@ -15,14 +16,8 @@ import jax
 import numpy as np
 
 from repro.kernels.threshold_select import ref
-from repro.kernels.threshold_select.threshold_select import _SLOT_TILE
 from repro.kernels.threshold_select.threshold_select import (
-    threshold_select_blocks as _kernel)
-
-
-def kernel_supported(block_n: int) -> bool:
-    """Whether the fused kernel's slot-tile layout covers this block size."""
-    return block_n % _SLOT_TILE == 0
+    LANES, threshold_select_rows)
 
 
 def default_backend() -> str:
@@ -32,33 +27,31 @@ def default_backend() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
-def threshold_select(scores, tau, *, block_n: int = 1024,
+def threshold_select(scores, tau, *, block_n: int = 4096,
                      backend: str = "auto") -> np.ndarray:
     """Ascending local indices of {i : scores[i] >= tau, scores[i] >= 0}.
 
     scores may be any host float array (np.memmap chunks included); entries
     below 0 are the "unscored" sentinel and are never selected. The kernel
-    path stitches per-block compacted indices with per-block counts on the
-    host — peak memory is O(len(scores)), so callers bound memory by
-    chunking the corpus, never by masking it whole.
+    path stitches per-row compacted lane ids on the host — peak memory is
+    O(len(scores)), so callers bound memory by chunking the corpus, never
+    by masking it whole. Raises ValueError for a kernel backend whose
+    tiling does not cover `block_n`.
     """
     n = int(np.asarray(scores).shape[0])
     if n == 0:
         return np.empty(0, np.int64)
     if backend == "auto":
         backend = default_backend()
-    if backend != "ref" and not kernel_supported(block_n):
-        backend = "ref"
     if backend == "ref":
         return ref.threshold_select_ref(scores, tau)
+    if backend not in ("pallas", "interpret"):
+        raise ValueError(f"unknown threshold_select backend {backend!r}")
 
-    idx, cnt = _kernel(np.asarray(scores, np.float32), float(tau),
-                       block_n=block_n, interpret=(backend == "interpret"))
-    idx = np.asarray(idx)
-    cnt = np.asarray(cnt)[:, 0].astype(np.int64)
-    nb = idx.shape[0]
-    lane = np.arange(block_n, dtype=np.int64)
-    keep = lane[None, :] < cnt[:, None]
-    base = (np.arange(nb, dtype=np.int64) * block_n)[:, None]
-    out = (idx.astype(np.int64) + base)[keep]   # row-major => ascending
-    return out
+    rows = np.asarray(threshold_select_rows(
+        np.asarray(scores, np.float32), float(tau), block_n=block_n,
+        interpret=(backend == "interpret"))).ravel()
+    # Flat position p = r * 128 + k holds row r's k-th selected lane id;
+    # row-major order keeps the stitched indices ascending.
+    pos = np.flatnonzero(rows >= 0)
+    return (pos - pos % LANES) + rows[pos].astype(np.int64)

@@ -4,24 +4,27 @@ Selection emission is the last O(n) pass of a SUPG query: once tau is
 estimated from the tiny labeled sample, every shard must be scanned for
 {x : A(x) >= tau}. Materializing a boolean mask per query costs one full
 host-side allocation per corpus; at 1e9 records that is the memory wall the
-streaming plane removes. This kernel fuses, per (1, block_n) score block:
+streaming plane removes. The scores are viewed as (rows, 128) — records on
+the 128 lanes — and the kernel fuses, per row of 128 records:
 
-    compare:  sel[i] = (A(x_i) >= tau) & (A(x_i) >= 0)   (-1 marks unscored
+    compare:  sel[j]  = (A(x_j) >= tau) & (A(x_j) >= 0)   (-1 marks unscored
               records / padding — they are never emitted, regardless of tau)
-    count:    cnt    = sum(sel)
-    compact:  idx[j] = i of the j-th selected record, j < cnt (block-local)
+    rank:     rank[j] = sel[0] + ... + sel[j]  (an MXU product of the row
+              with the 128 x 128 upper-triangular ones matrix)
+    compact:  out[k]  = j of the k-th selected record of the row, -1 for
+              k >= the row's count
 
-so one streaming read of the chunk yields dense per-block index lists whose
-total size is O(selected), not O(n). Compaction is resolved the same way
-score_hist resolves bin membership: the slot assignment pos = cumsum(sel)-1
-drives one-hot (block_n x 512) masks contracted against the block-local
-iota on the MXU (float32 is exact for indices < 2^24 >> block_n). Entries
-at slots >= cnt are matmul zeros; callers slice by cnt.
+The compaction is one more MXU product: the one-hot slot mask
+[rank[j] - 1 == k] & sel[j] contracted against the lane ids j + 1. Every
+operand is a small integer (0/1 masks, lane ids <= 128), which bfloat16
+holds exactly, so the products are exact at the MXU's default precision.
+The host stitches rows back together (`ops.threshold_select`): row r's
+entries >= 0, plus r * 128, are its selected indices in ascending order.
 
-Layout: grid (n_blocks,); tau rides in SMEM; outputs are (nb, block_n)
-compacted indices + (nb, 128) lane-broadcast counts. Compiled on TPU,
-`interpret=True` emulation elsewhere; the pure-numpy reference in ref.py is
-the non-tile-aligned / CPU-throughput fallback.
+Layout: grid (rows // block_rows,); each step streams one
+(block_n // 128, 128) tile — a whole number of (8, 128) float32 tiles —
+and writes the same-shaped int32 tile of compacted lane ids. tau rides in
+SMEM. Compiled on TPU, `interpret=True` emulation elsewhere.
 """
 from __future__ import annotations
 
@@ -32,64 +35,76 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_SLOT_TILE = 512
+LANES = 128
+SUBLANES = 8
+BLOCK_QUANTUM = LANES * SUBLANES      # block_n must be a multiple of this
 
 
-def _select_kernel(tau_ref, s_ref, idx_ref, cnt_ref, *, block_n):
+def _select_kernel(tau_ref, s_ref, idx_ref):
     tau = tau_ref[0]
-    s = s_ref[0].astype(jnp.float32)                  # (block_n,)
-    valid = s >= 0.0                                  # sentinel/padding = -1
-    sel = jnp.logical_and(valid, s >= tau)
-    self32 = sel.astype(jnp.float32)
-    pos = jnp.cumsum(self32) - 1.0                    # slot of each selected
-    local = jax.lax.broadcasted_iota(jnp.float32, (1, block_n), 1)
+    upper = jnp.where(
+        jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+        <= jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1), 1.0, 0.0)
+    slot_iota = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+    row_iota = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 0)
+    lane_ids = (jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 1)
+                + 1).astype(jnp.float32)
 
-    for t in range(block_n // _SLOT_TILE):
-        lo = t * _SLOT_TILE
-        slot_ids = lo + jax.lax.broadcasted_iota(
-            jnp.float32, (block_n, _SLOT_TILE), 1)
-        onehot = jnp.where(sel[:, None], (pos[:, None] == slot_ids)
-                           .astype(jnp.float32), 0.0)
-        compact = jax.lax.dot_general(
-            local, onehot, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # (1, _SLOT_TILE)
-        idx_ref[0, lo:lo + _SLOT_TILE] = compact[0]
-    cnt_ref[0, :] = jnp.full((128,), jnp.sum(self32), jnp.float32)
+    for g in range(s_ref.shape[0] // SUBLANES):
+        rows = slice(g * SUBLANES, (g + 1) * SUBLANES)
+        s = s_ref[rows, :].astype(jnp.float32)          # (8, 128)
+        sel = jnp.logical_and(s >= 0.0, s >= tau)       # padding = -1
+        rank = jax.lax.dot_general(
+            jnp.where(sel, 1.0, 0.0), upper, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        slot = jnp.where(sel, rank.astype(jnp.int32) - 1, -1)
+        out = jnp.zeros((SUBLANES, LANES), jnp.float32)
+        for r in range(SUBLANES):
+            onehot = jnp.where(slot[r:r + 1] == slot_iota, 1.0, 0.0)
+            # Every row of `lane_ids` is the same, so every row of the
+            # product is row r's compacted lane ids (+1, 0 for no record).
+            compact = jax.lax.dot_general(
+                lane_ids, onehot, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)     # (8, 128 slots)
+            out = jnp.where(row_iota == r, compact, out)
+        idx_ref[rows, :] = out.astype(jnp.int32) - 1
+
+
+def _check_layout(block_n: int) -> None:
+    """Raise ValueError unless the kernel's tiling covers this block."""
+    if block_n <= 0 or block_n % BLOCK_QUANTUM:
+        raise ValueError(
+            f"threshold_select kernel needs block_n to be a positive "
+            f"multiple of {BLOCK_QUANTUM}, got {block_n}")
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def threshold_select_blocks(scores, tau, block_n=1024, interpret=False):
+def threshold_select_rows(scores, tau, block_n=4096, interpret=False):
     """scores: (N,) float; entries < 0 (unscored sentinel/padding) are never
-    selected. Returns (idx, cnt): idx (nb, block_n) float32 block-local
-    compacted indices (garbage beyond the count), cnt (nb, 128) float32
-    per-block selected counts broadcast across lanes.
+    selected. Returns (ceil(N / block_n) * block_n // 128, 128) int32: row
+    r holds the ascending lane ids of the selected records among records
+    r * 128 ... r * 128 + 127, followed by -1s.
     """
-    assert block_n % _SLOT_TILE == 0
+    _check_layout(block_n)
     n = scores.shape[0]
     pad = (-n) % block_n
     if pad:
         scores = jnp.concatenate(
             [scores, jnp.full((pad,), -1.0, scores.dtype)])
-    nb = scores.shape[0] // block_n
-    blocks = scores.reshape(nb, block_n)
+    rows = scores.shape[0] // LANES
+    block_rows = block_n // LANES
     tau_arr = jnp.full((1,), tau, jnp.float32)
 
-    kernel = functools.partial(_select_kernel, block_n=block_n)
-    idx, cnt = pl.pallas_call(
-        kernel,
-        grid=(nb,),
+    return pl.pallas_call(
+        _select_kernel,
+        grid=(rows // block_rows,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_n), lambda i: (i, 0)),
+            pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_n), lambda i: (i, 0)),
-            pl.BlockSpec((1, 128), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb, block_n), jnp.float32),
-            jax.ShapeDtypeStruct((nb, 128), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(tau_arr, blocks)
-    return idx, cnt
+    )(tau_arr, scores.reshape(rows, LANES))

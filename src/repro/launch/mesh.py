@@ -13,18 +13,11 @@ optim/grad_compress.py).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 explicit-sharding API; absent on older installs
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -83,3 +83,14 @@ def test_local_selection_count():
     scores = jnp.asarray(np.linspace(0, 1, 1000).astype(np.float32))
     cnt = distributed.global_selection_count(mesh, scores, 0.25)
     assert float(cnt) == (np.linspace(0, 1, 1000) >= 0.25).sum()
+
+
+def test_build_sketch_raises_on_tpu_for_untiled_bins(monkeypatch):
+    """On a TPU the default sketch path is the compiled kernel: a bin count
+    its tiling cannot take raises instead of silently taking the jnp
+    reference (off TPU that reference still covers such counts)."""
+    s = jnp.linspace(0.0, 1.0, 2048)
+    assert float(binned.build_sketch(s, 1000).total) == 2048
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="num_bins"):
+        binned.build_sketch(s, 1000)

@@ -466,7 +466,9 @@ def test_restore_spends_nothing_with_audited_watch(tmp_path):
             time.sleep(0.01)
         st = srv.stats()
         assert st.sentinel_checks == 1          # epoch 2 only: 1 was done
-        assert st.records_labeled >= 64         # the probe hit the oracle
+        # The probe hit the oracle. Its 64 draws are with replacement,
+        # so a repeated record is answered by the cache, not relabeled.
+        assert st.records_labeled > 0
         # Tenant balance still equals the snapshot's: probes ride their
         # own throwaway ledger, and nothing certified was re-charged.
         assert st.tenants["t"].oracle_charged == snap_charged

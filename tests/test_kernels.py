@@ -160,13 +160,27 @@ def test_threshold_select_edge_cases():
 
 
 def test_threshold_select_non_tile_aligned_block_falls_back():
-    """block_n not covered by the slot-tile layout routes to the jnp/numpy
-    fallback instead of failing (same contract as score_hist)."""
-    assert not ts_ops.kernel_supported(300)
-    assert ts_ops.kernel_supported(1024)
+    """A block_n the (8, 128) tiling does not cover no longer falls back to
+    the numpy reference: a kernel backend raises, so a caller always knows
+    which path ran; the reference itself takes any block_n (same contract
+    as score_hist)."""
     s = np.random.default_rng(0).random(1000).astype(np.float32)
-    out = ts_ops.threshold_select(s, 0.5, block_n=300, backend="interpret")
+    with pytest.raises(ValueError, match="block_n"):
+        ts_ops.threshold_select(s, 0.5, block_n=300, backend="interpret")
+    np.testing.assert_array_equal(
+        ts_ops.threshold_select(s, 0.5, block_n=1024, backend="interpret"),
+        ts_ref.threshold_select_ref(s, 0.5))
+    out = ts_ops.threshold_select(s, 0.5, block_n=300, backend="ref")
     np.testing.assert_array_equal(out, ts_ref.threshold_select_ref(s, 0.5))
+
+
+@pytest.mark.parametrize("num_bins,block_n", [(1000, 8192), (4096, 300)])
+def test_score_hist_rejects_untiled_layout(num_bins, block_n):
+    """The sketch kernel raises for sizes its tiling cannot take instead of
+    rerouting to the jnp reference."""
+    s = jnp.linspace(0.0, 1.0, 2048)
+    with pytest.raises(ValueError, match="score_hist kernel"):
+        sh_ops.score_hist(s, num_bins, backend="interpret", block_n=block_n)
 
 
 def test_threshold_select_memmap_chunk(tmp_path):

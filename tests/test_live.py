@@ -233,7 +233,7 @@ def test_server_append_and_subscribe_refused_after_close(corpus):
 
 # -- merge/fold properties (satellite: split-corpus bitwise invariants) -----
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(st.integers(0, 2**31 - 1), st.integers(1, 400))
 def test_chunk_sketch_fold_split_invariance(seed, chunk):
     """Folding a prefix of per-chunk sketches, then merging the rest on
