@@ -130,10 +130,19 @@ than `chunk_records` is itself chunked and merged, so even engine build never
 materializes a full shard). On a real fleet each worker holds its shard and
 the driver runs where the coordinator lives; the collective math matches
 core/distributed.py.
+
+The query path opens `jax.profiler.TraceAnnotation` spans (`supg.round`,
+`supg.drain_wait`, `supg.sample[.rng|.chunk]`, `supg.bound`,
+`supg.emit[.chunk|.stitch]`, `supg.append.sketch`; see
+docs/architecture.md). They record only while a profiler trace runs. A
+query's spans carry its request id `q`, numbered from one when
+`QuerySession.submit` (or `run`) builds its plan; 0 marks work outside
+any request.
 """
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import logging
 import os
 import threading
@@ -144,6 +153,7 @@ from typing import (Dict, Generator, List, Optional, Sequence, Tuple,
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import binned, sampling, thresholds
 from repro.core.oracle import (BudgetLedger, DrainHandle, OracleClient,
@@ -155,6 +165,15 @@ from repro.kernels.threshold_select import ops as select_ops
 logger = logging.getLogger(__name__)
 
 _clamp_logged = False
+
+# Request ids for the profiler spans: one per plan, and the id of the
+# plan whose step runs on this thread (0 outside any plan step).
+_request_ids = itertools.count(1)
+_request = threading.local()
+
+
+def _current_q() -> int:
+    return getattr(_request, "q", 0)
 
 
 def _effective_workers(requested: Optional[int], clamp: bool) -> int:
@@ -594,8 +613,10 @@ class SelectionEngine:
             all_shards = st.shards + arrs
             sizes = [int(s.shape[0]) for s in all_shards]
             plan = pipeline.ChunkPlan(sizes, self.chunk_records)
-            new_sketches, new_masses = self._sketch_shards(
-                all_shards, plan, len(st.shards), kernel)
+            with TraceAnnotation("supg.append.sketch",
+                                 records=sum(sizes[len(st.shards):])):
+                new_sketches, new_masses = self._sketch_shards(
+                    all_shards, plan, len(st.shards), kernel)
             sketch = (binned.merge_sketches(st.sketch, *new_sketches)
                       if new_sketches else st.sketch)
             z_sqrt, z_prop, _ = binned.weight_normalizers(sketch)
@@ -688,43 +709,58 @@ class SelectionEngine:
         `state` pins a specific corpus epoch (default: current).
         """
         st = self._state if state is None else state
-        if scheme == "uniform":
-            idx = jax.random.randint(key, (s,), 0, st.n_total)
-            return np.asarray(idx, np.int64), np.ones(s, np.float32)
-        kappa = self.kappa if kappa is None else kappa
-        states = self._sampling_state(scheme, kappa, state=st)
-        mass = self._shard_masses(scheme, kappa, state=st)
-        k_alloc, k_chunk, k_rec = jax.random.split(key, 3)
-        alloc = np.asarray(jax.random.categorical(
-            k_alloc, jnp.log(jnp.asarray(mass, jnp.float32)), shape=(s,)))
-        u_chunk = np.asarray(jax.random.uniform(k_chunk, (s,)), np.float64)
-        u_rec = np.asarray(jax.random.uniform(k_rec, (s,)), np.float64)
-        out_idx = np.empty(s, np.int64)
-        out_m = np.empty(s, np.float32)
-        work = []    # (shard_id, chunk_id, draw positions into [0, s))
-        for sh, seg in self._group_sorted(alloc,
-                                          np.argsort(alloc, kind="stable")):
-            chunk_ids = sampling.draw_from_cdf(states[sh].cdf, u_chunk[seg])
-            for ci, grp in self._group_sorted(
-                    chunk_ids, np.argsort(chunk_ids, kind="stable")):
-                work.append((sh, ci, seg[grp]))
+        q = _current_q()
+        with TraceAnnotation("supg.sample", q=q, draws=int(s)) as trace:
+            if scheme == "uniform":
+                with TraceAnnotation("supg.sample.rng", q=q):
+                    idx = np.asarray(
+                        jax.random.randint(key, (s,), 0, st.n_total),
+                        np.int64)
+                trace.set_metadata(chunks=0)
+                return idx, np.ones(s, np.float32)
+            kappa = self.kappa if kappa is None else kappa
+            states = self._sampling_state(scheme, kappa, state=st)
+            mass = self._shard_masses(scheme, kappa, state=st)
+            with TraceAnnotation("supg.sample.rng", q=q):
+                k_alloc, k_chunk, k_rec = jax.random.split(key, 3)
+                alloc = np.asarray(jax.random.categorical(
+                    k_alloc, jnp.log(jnp.asarray(mass, jnp.float32)),
+                    shape=(s,)))
+                u_chunk = np.asarray(jax.random.uniform(k_chunk, (s,)),
+                                     np.float64)
+                u_rec = np.asarray(jax.random.uniform(k_rec, (s,)),
+                                   np.float64)
+            out_idx = np.empty(s, np.int64)
+            out_m = np.empty(s, np.float32)
+            work = []    # (shard_id, chunk_id, draw positions into [0, s))
+            for sh, seg in self._group_sorted(
+                    alloc, np.argsort(alloc, kind="stable")):
+                chunk_ids = sampling.draw_from_cdf(states[sh].cdf,
+                                                   u_chunk[seg])
+                for ci, grp in self._group_sorted(
+                        chunk_ids, np.argsort(chunk_ids, kind="stable")):
+                    work.append((sh, ci, seg[grp]))
 
-        chunk = st.plan.chunk_records
+            chunk = st.plan.chunk_records
 
-        def resolve(item):
-            sh, ci, pos = item
-            start = ci * chunk
-            p = sampling.defensive_probs(
-                st.shards[sh][start:start + chunk], scheme,
-                st.z[scheme], kappa, st.n_total)
-            local = sampling.draw_from_cdf(sampling.normalized_cdf(p),
-                                           u_rec[pos])
-            out_idx[pos] = st.offsets[sh] + start + local
-            out_m[pos] = (1.0 / st.n_total) / np.maximum(
-                p[local], 1e-38)
+            def resolve(item):
+                sh, ci, pos = item
+                start = ci * chunk
+                with TraceAnnotation("supg.sample.chunk", q=q,
+                                     shard=int(sh), chunk=int(ci),
+                                     draws=int(pos.size)):
+                    p = sampling.defensive_probs(
+                        st.shards[sh][start:start + chunk], scheme,
+                        st.z[scheme], kappa, st.n_total)
+                    local = sampling.draw_from_cdf(
+                        sampling.normalized_cdf(p), u_rec[pos])
+                    out_idx[pos] = st.offsets[sh] + start + local
+                    out_m[pos] = (1.0 / st.n_total) / np.maximum(
+                        p[local], 1e-38)
 
-        self.pool.map(resolve, work)
-        return out_idx, out_m
+            self.pool.map(resolve, work)
+            trace.set_metadata(chunks=len(work))
+            return out_idx, out_m
 
     def score_at(self, global_idx,
                  state: Optional[CorpusState] = None) -> np.ndarray:
@@ -803,13 +839,14 @@ class SelectionEngine:
                       "noci": "uniform"}[query.method]
             idx, m = self.draw_sample(key, s, scheme, state=st)
             o_s = yield OracleRequest(idx, ledger)
-            a_s = self.score_at(idx, state=st)
-            if query.method == "noci":
-                res = thresholds.tau_unoci_r(a_s, o_s, query.gamma)
-            else:
-                res = thresholds.tau_ci_r(a_s, o_s, m, query.gamma,
-                                          query.delta)
-            tau = float(res.tau)
+            with TraceAnnotation("supg.bound", q=_current_q()):
+                a_s = self.score_at(idx, state=st)
+                if query.method == "noci":
+                    res = thresholds.tau_unoci_r(a_s, o_s, query.gamma)
+                else:
+                    res = thresholds.tau_ci_r(a_s, o_s, m, query.gamma,
+                                              query.delta)
+                tau = float(res.tau)
         else:
             k0, k1 = jax.random.split(key)
             if query.method == "is" and query.two_stage:
@@ -824,24 +861,27 @@ class SelectionEngine:
                 idx1 = self._uniform_in_region(k1, s - s // 2, tau_dp,
                                                state=st)
                 o1 = yield OracleRequest(idx1, ledger)
-                a1 = self.score_at(idx1, state=st)
-                res = thresholds.tau_ci_p(a1, o1, query.gamma,
-                                          query.delta / 2.0,
-                                          min_step=query.min_step)
+                with TraceAnnotation("supg.bound", q=_current_q()):
+                    a1 = self.score_at(idx1, state=st)
+                    res = thresholds.tau_ci_p(a1, o1, query.gamma,
+                                              query.delta / 2.0,
+                                              min_step=query.min_step)
+                    tau = float(res.tau)
             else:
                 scheme = ("uniform" if query.method in ("uniform", "noci")
                           else query.weight_scheme)
                 idx, m = self.draw_sample(k0, s, scheme, state=st)
                 o_s = yield OracleRequest(idx, ledger)
-                a_s = self.score_at(idx, state=st)
-                if query.method == "noci":
-                    res = thresholds.tau_unoci_p(a_s, o_s, query.gamma)
-                else:
-                    res = thresholds.tau_ci_p(
-                        a_s, o_s, query.gamma, query.delta,
-                        m_s=None if scheme == "uniform" else m,
-                        min_step=query.min_step)
-            tau = float(res.tau)
+                with TraceAnnotation("supg.bound", q=_current_q()):
+                    a_s = self.score_at(idx, state=st)
+                    if query.method == "noci":
+                        res = thresholds.tau_unoci_p(a_s, o_s, query.gamma)
+                    else:
+                        res = thresholds.tau_ci_p(
+                            a_s, o_s, query.gamma, query.delta,
+                            m_s=None if scheme == "uniform" else m,
+                            min_step=query.min_step)
+                    tau = float(res.tau)
 
         pos = ledger.labeled_positives()
         walk, out_sink, finish = self._emission_walk(tau, pos, sink,
@@ -1102,11 +1142,15 @@ class SelectionEngine:
             raise
 
         def emit_span(span):
-            block = st.shards[span.shard_id][span.start:span.stop]
-            local = select_ops.threshold_select(
-                block, tau, backend=self.select_backend)
-            if local.size:
-                sink.emit(span.shard_id, span.start + local)
+            with TraceAnnotation("supg.emit.chunk", shard=span.shard_id,
+                                 chunk=span.chunk_id) as trace:
+                block = st.shards[span.shard_id][span.start:span.stop]
+                local = select_ops.threshold_select(
+                    block, tau, backend=self.select_backend)
+                if local.size:
+                    with TraceAnnotation("supg.emit.stitch"):
+                        sink.emit(span.shard_id, span.start + local)
+                trace.set_metadata(selected=int(local.size))
 
         def finish(oracle_calls: int) -> ShardedSelection:
             counts = sink.close()
@@ -1128,7 +1172,7 @@ class SelectionEngine:
         walk, out_sink, finish = self._emission_walk(tau, pos, sink,
                                                      chunk_records,
                                                      state=state)
-        err = pipeline.run_fused([walk], self.pool)[0]
+        err = _run_walks([walk], self.pool, _current_q())[0]
         if err is not None:
             # Emission died (e.g. a CallbackSink consumer raised): release
             # the sink so sequential reuse still works.
@@ -1156,59 +1200,72 @@ class SelectionEngine:
         device, never a correctness requirement).
         """
         st = self._state if state is None else state
-        plan = st.plan
-        spans = list(plan)
+        q = _current_q()
+        with TraceAnnotation("supg.sample", q=q, draws=int(s)) as trace:
+            plan = st.plan
+            spans = list(plan)
 
-        def count_span(span):
-            # Count through the exact same selection pass the resolve step
-            # uses: any dtype/backend rounding disagreement between the two
-            # would desynchronize ranks from region sizes.
-            return select_ops.threshold_select(
-                st.shards[span.shard_id][span.start:span.stop], tau,
-                backend=self.select_backend).size
+            def count_span(span):
+                # Count through the exact same selection pass the resolve
+                # step uses: any dtype/backend rounding disagreement between
+                # the two would desynchronize ranks from region sizes.
+                return select_ops.threshold_select(
+                    st.shards[span.shard_id][span.start:span.stop], tau,
+                    backend=self.select_backend).size
 
-        span_counts = self.pool.map(count_span, spans)
-        per_shard = [np.zeros(plan.num_chunks(sh), np.int64)
-                     for sh in range(len(st.shards))]
-        for span, c in zip(spans, span_counts):
-            per_shard[span.shard_id][span.chunk_id] = c
-        counts = np.asarray([pc.sum() for pc in per_shard], np.float64)
-        total = counts.sum()
-        if total == 0:
-            idx = jax.random.randint(key, (s,), 0, st.n_total)
-            return np.asarray(idx, np.int64)
-        mass = counts / total
-        k_alloc, k_draw = jax.random.split(key)
-        # log(0) = -inf => empty shards are excluded from the categorical.
-        alloc = np.asarray(jax.random.categorical(
-            k_alloc, jnp.log(jnp.asarray(mass, jnp.float32)), shape=(s,)))
-        out = np.empty(s, np.int64)
-        dkeys = jax.random.split(k_draw, len(st.shards))
-        work = []    # (shard_id, chunk_id, positions, in-chunk region ranks)
-        for sh, seg in self._group_sorted(alloc,
-                                          np.argsort(alloc, kind="stable")):
-            cum = np.concatenate([[0], np.cumsum(per_shard[sh])])
-            # uniform region ranks, then rank -> (chunk, offset-in-chunk);
-            # only chunks with nonzero region counts can be hit.
-            r = np.asarray(jax.random.randint(
-                dkeys[sh], (seg.size,), 0, int(cum[-1])), np.int64)
-            ch = np.searchsorted(cum, r, side="right") - 1
-            corder = np.argsort(ch, kind="stable")
-            for ci, grp in self._group_sorted(ch, corder):
-                work.append((sh, ci, seg[grp], r[grp] - cum[ci]))
+            span_counts = self.pool.map(count_span, spans)
+            per_shard = [np.zeros(plan.num_chunks(sh), np.int64)
+                         for sh in range(len(st.shards))]
+            for span, c in zip(spans, span_counts):
+                per_shard[span.shard_id][span.chunk_id] = c
+            counts = np.asarray([pc.sum() for pc in per_shard], np.float64)
+            total = counts.sum()
+            if total == 0:
+                with TraceAnnotation("supg.sample.rng", q=q):
+                    idx = np.asarray(jax.random.randint(
+                        key, (s,), 0, st.n_total), np.int64)
+                trace.set_metadata(chunks=0)
+                return idx
+            mass = counts / total
+            with TraceAnnotation("supg.sample.rng", q=q):
+                k_alloc, k_draw = jax.random.split(key)
+                # log(0) = -inf => empty shards are excluded from the
+                # categorical.
+                alloc = np.asarray(jax.random.categorical(
+                    k_alloc, jnp.log(jnp.asarray(mass, jnp.float32)),
+                    shape=(s,)))
+                dkeys = jax.random.split(k_draw, len(st.shards))
+            out = np.empty(s, np.int64)
+            work = []  # (shard_id, chunk_id, positions, in-chunk region ranks)
+            for sh, seg in self._group_sorted(
+                    alloc, np.argsort(alloc, kind="stable")):
+                cum = np.concatenate([[0], np.cumsum(per_shard[sh])])
+                # uniform region ranks, then rank -> (chunk, offset in
+                # chunk); only chunks with nonzero region counts can be hit.
+                with TraceAnnotation("supg.sample.rng", q=q):
+                    r = np.asarray(jax.random.randint(
+                        dkeys[sh], (seg.size,), 0, int(cum[-1])), np.int64)
+                ch = np.searchsorted(cum, r, side="right") - 1
+                corder = np.argsort(ch, kind="stable")
+                for ci, grp in self._group_sorted(ch, corder):
+                    work.append((sh, ci, seg[grp], r[grp] - cum[ci]))
 
-        chunk = plan.chunk_records
+            chunk = plan.chunk_records
 
-        def resolve(item):
-            sh, ci, pos, ranks = item
-            start = ci * chunk
-            region = select_ops.threshold_select(
-                st.shards[sh][start:start + chunk], tau,
-                backend=self.select_backend)
-            out[pos] = st.offsets[sh] + start + region[ranks]
+            def resolve(item):
+                sh, ci, pos, ranks = item
+                start = ci * chunk
+                with TraceAnnotation("supg.sample.chunk", q=q,
+                                     shard=int(sh), chunk=int(ci),
+                                     draws=int(pos.size)):
+                    region = select_ops.threshold_select(
+                        st.shards[sh][start:start + chunk], tau,
+                        backend=self.select_backend)
+                    out[pos] = st.offsets[sh] + start + region[ranks]
 
-        self.pool.map(resolve, work)
-        return out
+            self.pool.map(resolve, work)
+            trace.set_metadata(chunks=len(work))
+            return out
 
 
 # ---------------------------------------------------------------------------
@@ -1228,28 +1285,49 @@ def _drive_plan(plan, client: OracleClient,
     not raised from here directly: the suspended generator would otherwise
     stay alive on the exception's traceback with its cleanup (sink
     release) never run."""
-    send = None
-    while True:
-        try:
-            req = plan.send(send)
-        except StopIteration as done:
-            return done.value
-        try:
-            if isinstance(req, pipeline.ChunkWalk):
-                walk_err = pipeline.run_fused([req], pool)[0]
-                if walk_err is not None:
-                    raise walk_err
-                send = None
-            else:
-                send = client.submit(req.indices,
-                                     ledger=req.ledger).result()
-        except BaseException as err:  # noqa: BLE001 — rethrown in plan
+    q = next(_request_ids)
+    outer, _request.q = _current_q(), q
+    try:
+        send = None
+        while True:
             try:
-                plan.throw(err)       # runs the plan's except/finally
+                req = plan.send(send)
             except StopIteration as done:
-                return done.value     # plan absorbed the error gracefully
-            raise RuntimeError(
-                "plan yielded again after its request failed")
+                return done.value
+            try:
+                if isinstance(req, pipeline.ChunkWalk):
+                    walk_err = _run_walks([req], pool, q)[0]
+                    if walk_err is not None:
+                        raise walk_err
+                    send = None
+                else:
+                    send = client.submit(req.indices,
+                                         ledger=req.ledger).result()
+            except BaseException as err:  # noqa: BLE001 — rethrown in plan
+                try:
+                    plan.throw(err)       # runs the plan's except/finally
+                except StopIteration as done:
+                    return done.value     # plan absorbed the error
+                raise RuntimeError(
+                    "plan yielded again after its request failed")
+    finally:
+        _request.q = outer
+
+
+def _fused_spans(walks: Sequence[pipeline.ChunkWalk]) -> int:
+    """Chunk spans a fused pass over `walks` runs: each geometry once."""
+    geoms = {w.plan.geometry: w.plan.total_chunks for w in walks}
+    return sum(geoms.values())
+
+
+def _run_walks(walks: Sequence[pipeline.ChunkWalk],
+               pool: Optional[pipeline.WorkerPool], q: int) \
+        -> List[Optional[BaseException]]:
+    """`pipeline.run_fused` inside the `supg.emit` span; `q` is the first
+    walk's request id."""
+    with TraceAnnotation("supg.emit", q=q, walks=len(walks),
+                         spans=_fused_spans(walks)):
+        return pipeline.run_fused(walks, pool)
 
 
 _START = object()       # inbox sentinel: plan not yet started
@@ -1267,6 +1345,7 @@ class QueryHandle:
     def __init__(self, session: "QuerySession", query, sink):
         self.query = query
         self.sink = sink
+        self.q = next(_request_ids)      # request id on profiler spans
         self._session = session
         self._result: Optional[ShardedSelection] = None
         self._error: Optional[BaseException] = None
@@ -1510,7 +1589,8 @@ class QuerySession:
         and walk errors go back into exactly the plan that owns them."""
 
         def step(i):
-            _, plan, inbox = buf[i]
+            handle, plan, inbox = buf[i]
+            outer, _request.q = _current_q(), handle.q
             try:
                 if inbox is _START:
                     out = plan.send(None)
@@ -1522,6 +1602,8 @@ class QuerySession:
                 return ("done", done.value)
             except BaseException as err:  # noqa: BLE001 — owned by handle
                 return ("err", err)
+            finally:
+                _request.q = outer
             if isinstance(out, pipeline.ChunkWalk):
                 return ("walk", out)
             return ("req", out)
@@ -1539,15 +1621,11 @@ class QuerySession:
             if not walkers:
                 break
             walks = [outcomes[i][1] for i in walkers]
-            geoms: Dict[Tuple, pipeline.ChunkPlan] = {}
-            for w in walks:
-                geoms.setdefault(w.plan.geometry, w.plan)
             self.stats.fused_walks += len(walks)
             self.stats.walk_spans += sum(
                 w.plan.total_chunks for w in walks)
-            self.stats.fused_spans += sum(
-                p.total_chunks for p in geoms.values())
-            errs = pipeline.run_fused(walks, self.engine.pool)
+            self.stats.fused_spans += _fused_spans(walks)
+            errs = _run_walks(walks, self.engine.pool, buf[walkers[0]][0].q)
             for i, err in zip(walkers, errs):
                 # None resumes the plan past its walk; an error is thrown
                 # into it (releasing its sink) on the re-step below.
@@ -1563,7 +1641,9 @@ class QuerySession:
         handle, pending = self._outstanding
         self._outstanding = None
         t0 = time.perf_counter()
-        handle.wait()
+        with TraceAnnotation("supg.drain_wait", records=sum(
+                int(ticket.indices.size) for _, ticket in pending)):
+            handle.wait()
         self.stats.drain_wait_s += time.perf_counter() - t0
         self.stats.drain_busy_s += handle.duration_s
         self.stats.retries += handle.retries
@@ -1577,9 +1657,19 @@ class QuerySession:
                 slot[2] = err
 
     def _round(self) -> None:
-        """One scheduler turn: admit + step the current cohort (fusing
-        its walks), commit, resolve the other cohort's drain, then launch
-        this cohort's drain asynchronously and hand the turn over."""
+        """One scheduler turn inside the `supg.round` span, whose
+        arguments are the plan steps and fused walks it added to
+        `stats`."""
+        steps, walks = self.stats.plan_steps, self.stats.fused_walks
+        with TraceAnnotation("supg.round") as trace:
+            self._turn_once()
+            trace.set_metadata(plans=self.stats.plan_steps - steps,
+                               walks=self.stats.fused_walks - walks)
+
+    def _turn_once(self) -> None:
+        """Admit + step the current cohort (fusing its walks), commit,
+        resolve the other cohort's drain, then launch this cohort's drain
+        asynchronously and hand the turn over."""
         cur = self._turn
         buf = self._bufs[cur]
         self._admit(buf)

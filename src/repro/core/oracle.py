@@ -86,6 +86,7 @@ from typing import Callable, List, Optional, Protocol, Tuple, \
     runtime_checkable
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.resilience import (CircuitBreaker, CircuitOpenError,
                                    OracleMalformedError, OracleTimeoutError,
@@ -547,18 +548,24 @@ class BatchingOracle:
     def _resolve_guarded(self, tickets: List[Ticket]) -> None:
         if not tickets:
             return
-        try:
-            self._resolve(tickets)
-        except BaseException as err:
-            # Poisoned drain: every popped ticket must leave resolved —
-            # the ones the failure skipped carry the failure itself, so a
-            # later result() raises instead of returning stale labels
-            # (already-cached earlier micro-batches stay; they were
-            # labeled correctly).
-            for t in tickets:
-                if not t._done:
-                    t._error, t._done = err, True
-            raise
+        labeled, hits = self.records_labeled, self.cache_hits
+        with TraceAnnotation("supg.oracle.drain", records=sum(
+                int(t.indices.size) for t in tickets)) as trace:
+            try:
+                self._resolve(tickets)
+            except BaseException as err:
+                # Poisoned drain: every popped ticket must leave resolved
+                # — the ones the failure skipped carry the failure itself,
+                # so a later result() raises instead of returning stale
+                # labels (already-cached earlier micro-batches stay; they
+                # were labeled correctly).
+                for t in tickets:
+                    if not t._done:
+                        t._error, t._done = err, True
+                raise
+            finally:
+                trace.set_metadata(new=self.records_labeled - labeled,
+                                   cache_hits=self.cache_hits - hits)
 
     def drain_async(self) -> DrainHandle:
         """Start resolving everything pending on the drain thread.
@@ -731,11 +738,13 @@ class BatchingOracle:
             try:
                 if self._pacer is not None:
                     self._pacer(int(chunk.size))
-                if self.call_timeout_s is not None:
-                    labels = call_with_timeout(
-                        self._fn, chunk, self.call_timeout_s)
-                else:
-                    labels = self._fn(chunk)
+                with TraceAnnotation("supg.oracle.call",
+                                     records=int(chunk.size)):
+                    if self.call_timeout_s is not None:
+                        labels = call_with_timeout(
+                            self._fn, chunk, self.call_timeout_s)
+                    else:
+                        labels = self._fn(chunk)
                 labels = np.asarray(labels, np.float32).reshape(-1)
                 if labels.shape[0] != chunk.shape[0]:
                     raise OracleMalformedError(

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.kernels.threshold_select import ref
 from repro.kernels.threshold_select.threshold_select import (
@@ -53,5 +54,6 @@ def threshold_select(scores, tau, *, block_n: int = 4096,
         interpret=(backend == "interpret"))).ravel()
     # Flat position p = r * 128 + k holds row r's k-th selected lane id;
     # row-major order keeps the stitched indices ascending.
-    pos = np.flatnonzero(rows >= 0)
-    return (pos - pos % LANES) + rows[pos].astype(np.int64)
+    with TraceAnnotation("supg.emit.stitch"):
+        pos = np.flatnonzero(rows >= 0)
+        return (pos - pos % LANES) + rows[pos].astype(np.int64)

@@ -24,6 +24,7 @@ import threading
 from typing import Dict, List, Optional, Sequence, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.engine import CorpusState, SelectionEngine
 
@@ -67,18 +68,20 @@ class IngestPlane:
         engine's construction-time kernel choice for that pass); all other
         state updates are O(n_chunks) rebuilds from cached masses. Safe to
         call concurrently with query execution — in-flight plans keep
-        their pinned epoch.
+        their pinned epoch. Runs inside the `supg.append` profiler span.
         """
         if isinstance(shards, (list, tuple)):
             batch = list(shards)
         else:
             batch = [shards]
-        with self._lock:
+        with self._lock, TraceAnnotation("supg.append",
+                                         shards=len(batch)) as trace:
             before = self.engine.n_total
             state = self.engine._append_shards(batch, use_kernel=use_kernel)
             self._shard_count_at[state.epoch] = len(state.shards)
             self.appends += 1
             self.records_ingested += state.n_total - before
+            trace.set_metadata(records=state.n_total - before)
             return state.epoch
 
     def shards_since(self, epoch: int) -> List[int]:

@@ -43,6 +43,7 @@ import time
 from typing import Deque, Dict, List, Optional, Tuple, Union
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from repro.core.engine import (QueryHandle, QuerySession, SelectionEngine,
                                ShardedSelection)
@@ -693,68 +694,83 @@ class SelectionServer:
             # Session work runs outside the server lock: plans touch only
             # engine/channel state, and clients must be able to submit
             # (and read stats) while rounds are in flight.
-            for sq, ten, audit in subs:
-                self._registry.activate(sq, ledger_parent=ten.ledger)
-                if audit:
-                    base = (sq.key if sq.key is not None
-                            else jax.random.PRNGKey(0))
-                    self._awaiting_watch.append(
-                        (sq, jax.random.fold_in(base, 0x5E47)))
-            if self._awaiting_watch:
-                # Promote certified subscriptions to sentinel watches;
-                # the reference probe adopts the certified tau (no extra
-                # query budget spent).
-                keep = []
-                for sq, base in self._awaiting_watch:
-                    if not sq._certified.is_set():
-                        keep.append((sq, base))
-                        continue
-                    if sq._error is None:
-                        watch = self._sentinel.watch(sq.query, key=base,
-                                                     tau=sq.tau)
-                        self._watches.append([sq, watch, base, watch.epoch])
-                self._awaiting_watch = keep
-            # Sentinel audits run *before* the registry pumps, so a
-            # drifted epoch is re-emitted with the re-validated tau.
-            epoch = self.plane.epoch
-            for entry in self._watches:
-                sq, watch, base, last = entry
-                if epoch <= last:
+            with TraceAnnotation("supg.server.turn",
+                                 admitted=len(admitted)) as trace:
+                trace.set_metadata(finished=self._work_turn(admitted, subs))
+
+    def _work_turn(self, admitted: List[Tuple[ServerHandle, _Tenant]],
+                   subs: list) -> int:
+        """One working turn of the scheduler after admission: activate
+        subscriptions, run sentinel audits and standing catch-ups, submit
+        the admitted queries, step every session once and deliver the
+        finished queries. Returns how many finished."""
+        for sq, ten, audit in subs:
+            self._registry.activate(sq, ledger_parent=ten.ledger)
+            if audit:
+                base = (sq.key if sq.key is not None
+                        else jax.random.PRNGKey(0))
+                self._awaiting_watch.append(
+                    (sq, jax.random.fold_in(base, 0x5E47)))
+        if self._awaiting_watch:
+            # Promote certified subscriptions to sentinel watches;
+            # the reference probe adopts the certified tau (no extra
+            # query budget spent).
+            keep = []
+            for sq, base in self._awaiting_watch:
+                if not sq._certified.is_set():
+                    keep.append((sq, base))
                     continue
-                try:
-                    report = self._sentinel.audit(
-                        watch, key=jax.random.fold_in(base, epoch))
-                except BaseException as err:  # noqa: BLE001 — audit must
-                    # not kill the scheduler: a failed probe (oracle
-                    # fault, quota overrun) is recorded on the standing
-                    # query and the epoch is skipped, not retried hot.
-                    sq.last_error = err
-                else:
-                    if report.revalidated:
-                        sq.update_tau(watch.tau)
-                entry[3] = epoch
-            self._registry.pump()
-            for h, ten in admitted:
-                sess = min(self._sessions, key=lambda s: s.in_flight)
+                if sq._error is None:
+                    watch = self._sentinel.watch(sq.query, key=base,
+                                                 tau=sq.tau)
+                    self._watches.append([sq, watch, base, watch.epoch])
+            self._awaiting_watch = keep
+        # Sentinel audits run *before* the registry pumps, so a
+        # drifted epoch is re-emitted with the re-validated tau.
+        epoch = self.plane.epoch
+        for entry in self._watches:
+            sq, watch, base, last = entry
+            if epoch <= last:
+                continue
+            try:
+                report = self._sentinel.audit(
+                    watch, key=jax.random.fold_in(base, epoch))
+            except BaseException as err:  # noqa: BLE001 — audit must
+                # not kill the scheduler: a failed probe (oracle
+                # fault, quota overrun) is recorded on the standing
+                # query and the epoch is skipped, not retried hot.
+                sq.last_error = err
+            else:
+                if report.revalidated:
+                    sq.update_tau(watch.tau)
+            entry[3] = epoch
+        self._registry.pump()
+        for h, ten in admitted:
+            sess = min(self._sessions, key=lambda s: s.in_flight)
+            queued_us = int((time.monotonic() - h._t_submit) * 1e6)
+            with TraceAnnotation("supg.admit",
+                                 queued_us=queued_us) as trace:
                 qh = sess.submit(h.query, key=h._key, sink=h._sink,
                                  chunk_records=h._chunk_records,
                                  ledger_parent=ten.ledger)
-                self._inflight.append((h, qh, sess))
-            for sess in self._sessions:
-                sess.step()
-            self._registry.poll()
-            done = [(h, qh) for h, qh, _ in self._inflight if qh.done]
-            if done:
-                self._inflight = [t for t in self._inflight
-                                  if not t[1].done]
-                with self._cond:
-                    for h, qh in done:
-                        self._inflight_n -= 1
-                        try:
-                            self._finish_locked(h, result=qh.result())
-                        except BaseException as err:  # noqa: BLE001
-                            self._finish_locked(h, error=err)
-                    self._cond.notify_all()
+                trace.set_metadata(q=qh.q)
+            self._inflight.append((h, qh, sess))
+        for sess in self._sessions:
+            sess.step()
+        self._registry.poll()
+        done = [(h, qh) for h, qh, _ in self._inflight if qh.done]
+        if done:
+            self._inflight = [t for t in self._inflight
+                              if not t[1].done]
+            with self._cond:
+                for h, qh in done:
+                    self._inflight_n -= 1
+                    try:
+                        self._finish_locked(h, result=qh.result())
+                    except BaseException as err:  # noqa: BLE001
+                        self._finish_locked(h, error=err)
+                self._cond.notify_all()
+        return len(done)
 
     def _finish_locked(self, h: ServerHandle, result=None, error=None,
                        count: bool = True) -> None:
