@@ -210,7 +210,7 @@ def check_sketch(engine: SelectionEngine, shards) -> None:
     mismatched = []
     for sp in engine.plan:
         chunk = shards[sp.shard_id][sp.start:sp.stop]
-        sk, _, _ = binned.chunk_sketch_stats(chunk, NUM_BINS)
+        sk = binned.chunk_sketch_stats(chunk, NUM_BINS)[0]
         ids = np.minimum((np.clip(chunk, 0.0, 1.0) * np.float32(NUM_BINS))
                          .astype(np.int32), NUM_BINS - 1)
         counts = np.bincount(ids, minlength=NUM_BINS)

@@ -26,9 +26,9 @@ global Σ sqrt(A), Σ A and n extracted from one merged sketch are the only
 cross-shard quantities the defensive-mixture draw probabilities need, so the
 engine never re-reduces raw shards per query. `chunk_sketch_stats` is the
 per-chunk unit of the engine's streaming construction pass: it fuses the
-sketch reduction with the float64 per-chunk raw masses the hierarchical
-(shard → chunk → record) sampler persists, so bounded-memory importance
-sampling costs no extra data pass.
+sketch reduction with the float64 per-chunk and per-block raw masses the
+hierarchical (shard → chunk → block → record) sampler persists, so
+bounded-memory importance sampling costs no extra data pass.
 """
 from __future__ import annotations
 
@@ -92,22 +92,22 @@ def build_sketch(scores, num_bins=DEFAULT_BINS, use_kernel=None):
 
 
 def chunk_sketch_stats(scores_chunk, num_bins=DEFAULT_BINS, use_kernel=None
-                       ) -> Tuple[ScoreSketch, float, float]:
+                       ) -> Tuple[ScoreSketch, float, float, np.ndarray,
+                                  np.ndarray]:
     """One streaming-pass unit over a chunk: its ScoreSketch plus the raw
-    sampling masses (float64 Σ sqrt(A), Σ A) the hierarchical sampler
-    persists per chunk.
+    sampling masses the hierarchical sampler persists — float64 Σ sqrt(A)
+    and Σ A over the chunk, then per `sampling.BLOCK_RECORDS`-record block.
 
-    The chunk is already in cache for the sketch reduction, so the two
-    extra sums are effectively free — this is what lets the engine cache
-    O(n / chunk_records) sampling state instead of per-record CDFs.
+    The chunk is already in cache for the sketch reduction, so the extra
+    sums are effectively free — this is what lets the engine cache
+    O(n / BLOCK_RECORDS) sampling state instead of per-record CDFs.
     """
     from repro.core import sampling
 
     chunk32 = np.ascontiguousarray(scores_chunk, np.float32)
     sketch = build_sketch(jnp.asarray(chunk32), num_bins,
                           use_kernel=use_kernel)
-    s_sqrt, s_a = sampling.chunk_raw_masses(chunk32)
-    return sketch, s_sqrt, s_a
+    return (sketch, *sampling.chunk_raw_masses(chunk32))
 
 
 def merge_sketches(*sketches):

@@ -9,17 +9,18 @@ Construction (one chunked pass over the shards, ChunkPlan-driven):
   1. per-chunk `binned.chunk_sketch_stats` — the fused Pallas score_hist
      sketch (compiled on TPU, interpret-mode on CPU; jnp fallback for
      non-tile-aligned bin counts) plus the chunk's float64 raw sampling
-     masses (Σ sqrt(A), Σ A) in the same pass — merged into per-shard and
+     masses (Σ sqrt(A), Σ A) over the chunk and over each of its
+     1,024-record blocks in the same pass — merged into per-shard and
      global sketches (one psum of 48 KiB on a fleet),
-  2. hierarchical sampling state: the per-chunk raw masses are the *only*
-     persistent per-data sampling state — O(n / chunk_records) floats per
-     (shard, scheme), never per-record arrays. Per (scheme, kappa) the
-     engine caches the per-shard chunk-mass CDFs (a chunk's defensive mass
-     is (1-kappa)·Σraw/Z + kappa·|chunk|/n, from the cached sums alone);
-     the normalizers (Z_sqrt, Z_prop, n) come from
+  2. hierarchical sampling state: the per-chunk and per-block raw masses
+     are the *only* persistent per-data sampling state — O(n / 1,024)
+     floats per shard (16 B a block), never per-record arrays. Per
+     (scheme, kappa) the engine caches the per-shard chunk-mass CDFs (a
+     chunk's defensive mass is (1-kappa)·Σraw/Z + kappa·|chunk|/n, from
+     the cached sums alone); the normalizers (Z_sqrt, Z_prop, n) come from
      `binned.weight_normalizers` on the merged sketch,
-  3. shard-level sampling masses for the (shard → chunk → record) draw are
-     the per-shard sums of those chunk masses.
+  3. shard-level sampling masses for the (shard → chunk → block → record)
+     draw are the per-shard sums of those chunk masses.
 
 Every chunked walk — sketch construction, selection emission, the PT
 stage-2 region draw, and query-time chunk-draw resolution — iterates the
@@ -39,11 +40,13 @@ Query execution (zero O(n) *state* per query):
 
   * `draw_sample`   — multinomial over cached shard masses, then an
                       inverse-CDF draw over the cached chunk-mass CDF, then
-                      an exact within-chunk inverse-CDF draw over freshly
-                      computed weights streaming *only the allocated
-                      chunks*; chunk mass × within-chunk p reproduces the
-                      defensive-mixture p(x) exactly, so the m(x) factors
-                      are globally correct with O(chunk) transient memory,
+                      a search over the chunk's block-mass prefix (from the
+                      cached block sums), then an exact inverse-CDF draw
+                      over freshly computed weights of *only the blocks
+                      hit*, each read once; block mass × within-block p
+                      reproduces the defensive-mixture p(x) exactly, so the
+                      m(x) factors are globally correct with O(chunk)
+                      transient memory at most,
   * `score_at`      — `np.searchsorted` shard routing + per-shard fancy
                       gathers (no per-element Python loop),
   * tau estimation  — the exact sample-level estimators (Algorithms 2-5;
@@ -71,8 +74,8 @@ lazy view whose `total_selected` comes from per-shard counts, boolean masks
 only materialize if a caller explicitly asks for them, and the PT stage-2
 uniform-in-D' draw is rank-routed through the same chunked pass. The former
 O(n) surface — dense per-record inverse-CDF state behind `method="is"` —
-is gone: persistent sampling state is ≤ n / chunk_records entries per
-(shard, scheme) and record-level draws stream only their allocated chunks,
+is gone: persistent sampling state is ≤ n / 1,024 entries per (shard,
+scheme) and record-level draws read only the blocks they fall in,
 so the `weight_schemes=()` escape hatch is no longer needed (the argument
 is kept as a cache pre-warm hint).
 
@@ -399,8 +402,8 @@ class SelectionEngine:
         #    span yields its ScoreSketch *and* its raw sampling masses in
         #    one touch of the data. Sketches merge additively into
         #    per-shard and global sketches, so even memmap shards never
-        #    materialize whole; the per-chunk masses become the persistent
-        #    O(n / chunk_records) hierarchical sampling state. The same
+        #    materialize whole; the per-chunk and per-block masses become
+        #    the persistent O(n / 1,024) hierarchical sampling state. The same
         #    pass, restricted to appended shards only, is how the live
         #    plane extends an epoch (`_append_shards`).
         shard_sketches, chunk_masses = self._sketch_shards(
@@ -448,10 +451,11 @@ class SelectionEngine:
             spans)
         k = len(shards) - first_shard
         parts: List[List] = [[] for _ in range(k)]
-        sums: List[List[Tuple[float, float, int]]] = [[] for _ in range(k)]
-        for sp, (sk, s_sqrt, s_a) in zip(spans, stats):
+        sums: List[List[Tuple]] = [[] for _ in range(k)]
+        for sp, (sk, s_sqrt, s_a, b_sqrt, b_a) in zip(spans, stats):
             parts[sp.shard_id - first_shard].append(sk)
-            sums[sp.shard_id - first_shard].append((s_sqrt, s_a, sp.size))
+            sums[sp.shard_id - first_shard].append(
+                (s_sqrt, s_a, sp.size, b_sqrt, b_a))
         # Empty shards get an all-zero sketch via the jnp path (the kernel
         # grid cannot span a zero-length operand).
         sketches = [
@@ -463,7 +467,9 @@ class SelectionEngine:
             sampling.ChunkMasses(
                 np.asarray([t[0] for t in ss], np.float64),
                 np.asarray([t[1] for t in ss], np.float64),
-                np.asarray([t[2] for t in ss], np.int64))
+                np.asarray([t[2] for t in ss], np.int64),
+                np.concatenate([t[3] for t in ss]),
+                np.concatenate([t[4] for t in ss]))
             if ss else sampling.ChunkMasses.empty()
             for ss in sums]
         return sketches, masses
@@ -695,18 +701,24 @@ class SelectionEngine:
                     state: Optional[CorpusState] = None):
         """Global with-replacement draws; returns (global_idx, m).
 
-        Hierarchical (shard → chunk → record): multinomial over cached
-        shard masses, inverse-CDF over each shard's cached chunk-mass CDF,
-        then an exact within-chunk inverse-CDF draw over freshly computed
-        p(x) — only the allocated chunks are ever streamed, so transient
-        memory is O(chunk) and persistent state O(n_chunks). The joint
-        draw probability telescopes to the global defensive-mixed p(x)
-        (shard mass = Σ chunk masses, chunk mass = Σ p(x) over the chunk),
-        so m(x) = (1/n) / p(x) is globally correct. Draws are grouped by
-        shard and chunk with argsorts (no per-shard mask scans) and chunk
-        resolution runs through the worker pool; outputs land in
-        preassigned slots, so results are identical at any worker count.
-        `state` pins a specific corpus epoch (default: current).
+        Hierarchical (shard → chunk → block → record): multinomial over
+        cached shard masses, inverse-CDF over each shard's cached chunk-
+        mass CDF, then, per allocated chunk, `sampling.draw_in_blocks`:
+        a search over the chunk's block-mass prefix (cached raw block sums)
+        and an exact inverse-CDF draw over freshly computed p(x) of each
+        distinct 1,024-record block hit — so a draw reads about a block,
+        not a chunk, and no record is read twice. Transient memory is
+        O(chunk) at most; persistent state O(n / 1,024). The joint draw
+        probability telescopes to the global defensive-mixed p(x) (shard
+        mass = Σ chunk masses, chunk mass = Σ block masses, block mass =
+        Σ p(x) over the block), so m(x) = (1/n) / p(x) is globally
+        correct. Draws are grouped by shard and chunk with argsorts (no
+        per-shard mask scans) and chunk resolution runs through the worker
+        pool; outputs land in preassigned slots, so results are identical
+        at any worker count. The spans count the work: each
+        `supg.sample.chunk` its distinct `blocks` and the `records` whose
+        p(x) it computed, `supg.sample` the query's `records`. `state`
+        pins a specific corpus epoch (default: current).
         """
         st = self._state if state is None else state
         q = _current_q()
@@ -716,7 +728,7 @@ class SelectionEngine:
                     idx = np.asarray(
                         jax.random.randint(key, (s,), 0, st.n_total),
                         np.int64)
-                trace.set_metadata(chunks=0)
+                trace.set_metadata(chunks=0, records=0)
                 return idx, np.ones(s, np.float32)
             kappa = self.kappa if kappa is None else kappa
             states = self._sampling_state(scheme, kappa, state=st)
@@ -748,18 +760,19 @@ class SelectionEngine:
                 start = ci * chunk
                 with TraceAnnotation("supg.sample.chunk", q=q,
                                      shard=int(sh), chunk=int(ci),
-                                     draws=int(pos.size)):
-                    p = sampling.defensive_probs(
-                        st.shards[sh][start:start + chunk], scheme,
-                        st.z[scheme], kappa, st.n_total)
-                    local = sampling.draw_from_cdf(
-                        sampling.normalized_cdf(p), u_rec[pos])
-                    out_idx[pos] = st.offsets[sh] + start + local
-                    out_m[pos] = (1.0 / st.n_total) / np.maximum(
-                        p[local], 1e-38)
+                                     draws=int(pos.size)) as span:
+                    got = sampling.draw_in_blocks(
+                        st.shards[sh][start:start + chunk],
+                        st.chunk_masses[sh].block_raw(scheme, ci),
+                        u_rec[pos], scheme, st.z[scheme], kappa, st.n_total)
+                    span.set_metadata(blocks=got.blocks, records=got.records)
+                    out_idx[pos] = st.offsets[sh] + start + got.local
+                    out_m[pos] = (1.0 / st.n_total) / np.maximum(got.p,
+                                                                 1e-38)
+                return got.records
 
-            self.pool.map(resolve, work)
-            trace.set_metadata(chunks=len(work))
+            records = sum(self.pool.map(resolve, work))
+            trace.set_metadata(chunks=len(work), records=records)
             return out_idx, out_m
 
     def score_at(self, global_idx,

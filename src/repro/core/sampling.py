@@ -118,18 +118,24 @@ def sample_weighted_masked(key, probs, mask, s):
 # ---------------------------------------------------------------------------
 # Host-side CDF primitives for the engine's cached sampling state
 # ---------------------------------------------------------------------------
-# The SelectionEngine's cached state is *hierarchical*: per (shard, scheme)
-# it persists only the per-chunk raw masses accumulated during the sketch
-# pass — O(n / chunk_records) floats — and resolves record-level draws at
-# query time by streaming just the allocated chunks (categorical over chunk
-# masses, then an exact inverse-CDF draw over freshly computed within-chunk
-# weights). Because a chunk's defensive-mixture mass is exactly the sum of
-# its records' p(x), chunk mass × within-chunk p reproduces the global p(x),
-# so m(x) = (1/n)/p(x) stays exact with no O(n) state. float64 keeps the
-# prefix sums faithful at 1e8+ records.
+# The SelectionEngine's cached state is *hierarchical*: per shard it
+# persists only raw masses accumulated during the sketch pass — per chunk
+# and per BLOCK_RECORDS-record block, O(n / BLOCK_RECORDS) floats — and
+# resolves record-level draws at query time in three steps: a categorical
+# over chunk masses, a search over the allocated chunk's block-mass prefix,
+# then an exact inverse-CDF draw over freshly computed p(x) of just the
+# blocks hit (`draw_in_blocks`). Because a chunk's (block's) defensive-
+# mixture mass is exactly the sum of its records' p(x), the telescoped
+# product reproduces the global p(x), so m(x) = (1/n)/p(x) stays exact with
+# no O(n) state. float64 keeps the prefix sums faithful at 1e8+ records.
+
+BLOCK_RECORDS = 1024   # records a block: the within-chunk unit of a draw
+
 
 def normalized_cdf(weights) -> np.ndarray:
-    """Inclusive float64 prefix CDF, renormalized to end exactly at 1."""
+    """Inclusive float64 prefix CDF, renormalized to end exactly at 1
+    (over a whole chunk's p(x): the reference `draw_in_blocks` is tested
+    against)."""
     w = np.asarray(weights, np.float64)
     cdf = np.cumsum(w)
     total = cdf[-1] if cdf.size else 0.0
@@ -145,34 +151,55 @@ def draw_from_cdf(cdf: np.ndarray, u) -> np.ndarray:
 
 
 class ChunkMasses(NamedTuple):
-    """Per-chunk raw sampling masses for one shard (the persistent half of
-    the hierarchical sampler — O(n_chunks), never O(n_records)).
+    """Per-chunk and per-block raw sampling masses for one shard (the
+    persistent half of the hierarchical sampler — O(n / BLOCK_RECORDS),
+    never O(n_records)).
 
     Accumulated during the chunked sketch pass at engine construction: the
-    chunk is already in cache there, so the two extra float64 reductions are
-    effectively free. `sizes` counts *all* records in the chunk (unscored
-    sentinels included) because the defensive uniform component kappa/n
-    gives every record mass, exactly like the dense p(x) formula.
+    chunk is already in cache there, so the extra float64 reductions are
+    effectively free. Blocks are BLOCK_RECORDS consecutive records of one
+    chunk (a chunk's last block may be shorter, none straddles chunks), laid
+    out chunk after chunk. The sums are raw — independent of Z, kappa and
+    n — so an append only adds its own shards' entries. `sizes` counts
+    *all* records in the chunk (unscored sentinels included) because the
+    defensive uniform component kappa/n gives every record mass, exactly
+    like the dense p(x) formula.
     """
 
     sum_sqrt: np.ndarray   # (n_chunks,) float64 Σ sqrt(clip(A)) per chunk
     sum_a: np.ndarray      # (n_chunks,) float64 Σ clip(A) per chunk
     sizes: np.ndarray      # (n_chunks,) int64 record count per chunk
+    block_sqrt: np.ndarray  # (n_blocks,) float64 Σ sqrt(clip(A)) per block
+    block_a: np.ndarray     # (n_blocks,) float64 Σ clip(A) per block
 
     def raw(self, scheme: str) -> np.ndarray:
         return self.sum_sqrt if scheme == "sqrt" else self.sum_a
 
+    def block_raw(self, scheme: str, chunk_id: int) -> np.ndarray:
+        """One chunk's per-block raw masses for `scheme`."""
+        nb = -(-self.sizes // BLOCK_RECORDS)
+        start = int(nb[:chunk_id].sum())
+        blocks = self.block_sqrt if scheme == "sqrt" else self.block_a
+        return blocks[start:start + int(nb[chunk_id])]
+
     @classmethod
     def empty(cls) -> "ChunkMasses":
         return cls(np.empty(0, np.float64), np.empty(0, np.float64),
-                   np.empty(0, np.int64))
+                   np.empty(0, np.int64), np.empty(0, np.float64),
+                   np.empty(0, np.float64))
 
 
-def chunk_raw_masses(scores_chunk) -> Tuple[float, float]:
-    """Float64 Σ sqrt(A) and Σ A over one chunk (sentinels contribute 0)."""
+def chunk_raw_masses(scores_chunk
+                     ) -> Tuple[float, float, np.ndarray, np.ndarray]:
+    """Float64 Σ sqrt(A) and Σ A over one chunk, then the same two sums
+    per BLOCK_RECORDS-record block (sentinels contribute 0)."""
     a = np.clip(np.asarray(scores_chunk, np.float32), 0.0, 1.0)
-    return (float(np.sum(np.sqrt(a), dtype=np.float64)),
-            float(np.sum(a, dtype=np.float64)))
+    r = np.sqrt(a)
+    starts = np.arange(0, a.shape[0], BLOCK_RECORDS)
+    return (float(np.sum(r, dtype=np.float64)),
+            float(np.sum(a, dtype=np.float64)),
+            np.add.reduceat(r, starts, dtype=np.float64),
+            np.add.reduceat(a, starts, dtype=np.float64))
 
 
 def defensive_chunk_mass(raw: np.ndarray, sizes: np.ndarray, z: float,
@@ -244,6 +271,55 @@ def defensive_probs(scores_chunk, scheme: str, z: float, kappa: float,
     a = np.clip(np.asarray(scores_chunk, np.float32), 0.0, 1.0)
     raw = np.sqrt(a) if scheme == "sqrt" else a
     return ((1.0 - kappa) * raw / z + kappa / n_total).astype(np.float32)
+
+
+class BlockDraw(NamedTuple):
+    """Draws resolved inside one chunk by `draw_in_blocks`."""
+
+    local: np.ndarray   # (d,) int64 record index within the chunk
+    p: np.ndarray       # (d,) float32 p(x) of the drawn records
+    blocks: int         # distinct blocks the draws fell in
+    records: int        # records whose p(x) was computed
+
+
+def draw_in_blocks(chunk, block_raw: np.ndarray, u, scheme: str, z: float,
+                   kappa: float, n_total: int) -> BlockDraw:
+    """Inverse-CDF draws within one chunk, reading only the blocks hit.
+
+    The chunk's defensive block-mass prefix M_b = (1-kappa)·R_b/Z +
+    kappa·C_b/n (R_b the raw block-mass prefix, C_b the record-count
+    prefix) places each target T = u·M_last in a block; p(x) is computed
+    over each distinct block hit, once, and T − M_{b-1} is searched in
+    that block's float64 prefix sum. The same u picks the record a whole-
+    chunk CDF would but at float rounding edges, and p is `defensive_probs`
+    itself, so m(x) = (1/n)/p(x) matches it bit for bit.
+    """
+    size = chunk.shape[0]
+    n_blocks = block_raw.shape[0]
+    z = max(float(z), 1e-30)
+    counts = np.minimum(np.arange(1, n_blocks + 1) * BLOCK_RECORDS, size)
+    prefix = ((1.0 - kappa) * np.cumsum(block_raw) / z
+              + kappa * counts / n_total)
+    target = np.asarray(u, np.float64) * prefix[-1]
+    blk = np.minimum(np.searchsorted(prefix, target, side="left"),
+                     n_blocks - 1)
+    hit, row = np.unique(blk, return_inverse=True)
+    # One gather of every hit block; a short last block is padded with
+    # its final record at zero mass.
+    idx = hit[:, None] * BLOCK_RECORDS + np.arange(BLOCK_RECORDS)
+    p = defensive_probs(np.asarray(chunk)[np.minimum(idx, size - 1)],
+                        scheme, z, kappa, n_total)
+    p[idx >= size] = 0.0
+    cum = np.cumsum(p, axis=1, dtype=np.float64)
+    offset = target - np.where(blk > 0, prefix[blk - 1], 0.0)
+    # Complex numbers order lexicographically (real, then imaginary), so
+    # one searchsorted finds every draw's record in its own block's row.
+    keys = (np.arange(hit.size)[:, None] + 1j * cum).ravel()
+    found = np.searchsorted(keys, row + 1j * offset, side="left")
+    lengths = np.minimum(size - hit * BLOCK_RECORDS, BLOCK_RECORDS)
+    local = np.minimum(found - row * BLOCK_RECORDS, lengths[row] - 1)
+    return BlockDraw(hit[row] * BLOCK_RECORDS + local, p[row, local],
+                     int(hit.size), int(lengths.sum()))
 
 
 @functools.partial(jax.jit, static_argnames=("s", "scheme", "defensive"))

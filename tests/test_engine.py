@@ -361,6 +361,127 @@ def test_sampling_state_is_chunk_level():
         assert int(cm.sizes.sum()) == shards[sh].shape[0]
 
 
+@pytest.mark.parametrize("chunk", [1500, 3000, 4096])
+def test_block_masses_partition_chunk_masses(chunk):
+    """Persistent block state is ⌈chunk/1024⌉ float64 sums a chunk for
+    each scheme, none straddling a chunk, adding up to the chunk sums."""
+    rng = np.random.default_rng(4)
+    shards = [rng.random(n).astype(np.float32) for n in (9000, 0, 100, 4096)]
+    shards[0][::11] = -1.0                          # unscored sentinels
+    engine = SelectionEngine(shards, num_bins=512, chunk_records=chunk)
+    for sh, cm in enumerate(engine._chunk_masses):
+        n_blocks = int((-(-cm.sizes // 1024)).sum())
+        assert cm.block_sqrt.shape == cm.block_a.shape == (n_blocks,)
+        assert cm.block_sqrt.dtype == cm.block_a.dtype == np.float64
+        for ci, span in enumerate(engine.plan.shard_spans(sh)):
+            nb = -(-span.size // 1024)
+            for scheme in ("sqrt", "prop"):
+                blocks = cm.block_raw(scheme, ci)
+                assert blocks.size == nb
+                np.testing.assert_allclose(blocks.sum(), cm.raw(scheme)[ci],
+                                           rtol=1e-12)
+
+
+def test_block_masses_append_matches_cold_build():
+    """An append adds block sums for the appended shards only: the old
+    shards' arrays are the same objects, and every array is bit-identical
+    to a cold build over the same shards."""
+    rng = np.random.default_rng(6)
+    shards = [rng.beta(0.2, 1.0, n).astype(np.float32)
+              for n in (5000, 3100, 0, 7000)]
+    eng = SelectionEngine(shards[:2], num_bins=256, chunk_records=3000,
+                          weight_schemes=("sqrt", "prop"))
+    old = list(eng._chunk_masses)
+    eng._append_shards(shards[2:])
+    cold = SelectionEngine(shards, num_bins=256, chunk_records=3000,
+                           weight_schemes=("sqrt", "prop"))
+    assert all(a is b for a, b in zip(eng._chunk_masses[:2], old))
+    assert len(eng._chunk_masses) == len(cold._chunk_masses) == 4
+    for got, want in zip(eng._chunk_masses, cold._chunk_masses):
+        for field in got._fields:
+            np.testing.assert_array_equal(getattr(got, field),
+                                          getattr(want, field))
+    for key, states in cold._sampling_cache.items():
+        for a, b in zip(eng._sampling_cache[key], states):
+            np.testing.assert_array_equal(a.cdf, b.cdf)
+
+
+def _whole_chunk_draw(engine, key, s, scheme):
+    """`draw_sample` as it resolved a chunk before blocks: the inverse
+    CDF of `defensive_probs` over the whole allocated chunk, searched
+    with the same uniforms."""
+    import jax.numpy as jnp
+
+    from repro.core import sampling
+
+    st = engine._state
+    states = engine._sampling_state(scheme, engine.kappa)
+    mass = engine._shard_masses(scheme, engine.kappa)
+    k_alloc, k_chunk, k_rec = jax.random.split(key, 3)
+    alloc = np.asarray(jax.random.categorical(
+        k_alloc, jnp.log(jnp.asarray(mass, jnp.float32)), shape=(s,)))
+    u_chunk = np.asarray(jax.random.uniform(k_chunk, (s,)), np.float64)
+    u_rec = np.asarray(jax.random.uniform(k_rec, (s,)), np.float64)
+    idx, m = np.empty(s, np.int64), np.empty(s, np.float32)
+    chunk = st.plan.chunk_records
+    for sh in np.unique(alloc):
+        seg = np.flatnonzero(alloc == sh)
+        cis = sampling.draw_from_cdf(states[sh].cdf, u_chunk[seg])
+        for ci in np.unique(cis):
+            pos = seg[cis == ci]
+            start = ci * chunk
+            p = sampling.defensive_probs(
+                st.shards[sh][start:start + chunk], scheme,
+                st.z[scheme], engine.kappa, st.n_total)
+            local = sampling.draw_from_cdf(sampling.normalized_cdf(p),
+                                           u_rec[pos])
+            idx[pos] = st.offsets[sh] + start + local
+            m[pos] = (1.0 / st.n_total) / np.maximum(p[local], 1e-38)
+    return idx, m
+
+
+@pytest.mark.parametrize("layout", ["chunk1500", "chunk3000", "scorestore",
+                                    "dedup"])
+@pytest.mark.parametrize("scheme", ["sqrt", "prop"])
+def test_block_draw_matches_whole_chunk_draw(tmp_path, scheme, layout):
+    """For a fixed key the block resolve picks the record the whole-chunk
+    inverse CDF picks for at least 99.9% of draws, with the same m there
+    exactly: partial blocks (chunks of 1,500 and 3,000 records), an empty
+    shard, unscored sentinels, a memmap ScoreStore shard, and chunks with
+    far more draws than blocks, whose records are each read once."""
+    from repro.core import sampling
+
+    rng = np.random.default_rng(31)
+    chunk, s = (1500 if layout == "chunk1500" else 3000), 20_000
+    shards = [rng.beta(0.1, 1.0, n).astype(np.float32)
+              for n in (40_000, 0, 23_456)]
+    shards[0][rng.integers(0, 40_000, 3000)] = -1.0   # unscored sentinels
+    if layout == "scorestore":
+        store = ScoreStore(tmp_path / "s.scores", 30_000, create=True)
+        store.write(0, rng.beta(0.1, 1.0, 25_000).astype(np.float32))
+        shards[1] = store                           # last 5,000 unscored
+    if layout == "dedup":
+        shards = [rng.beta(0.1, 1.0, 7000).astype(np.float32)]
+    engine = SelectionEngine(shards, num_bins=512, chunk_records=chunk)
+    key = jax.random.PRNGKey(41)
+    idx, m = engine.draw_sample(key, s, scheme)
+    ref_idx, ref_m = _whole_chunk_draw(engine, key, s, scheme)
+    same = idx == ref_idx
+    assert same.mean() >= 0.999, same.mean()
+    np.testing.assert_array_equal(m[same], ref_m[same])
+    if layout == "dedup":                  # ~6,700 draws a 3-block chunk
+        st = engine._state
+        for ci in range(engine.plan.num_chunks(0)):
+            size = engine.plan.shard_spans(0)[ci].size
+            got = sampling.draw_in_blocks(
+                st.shards[0][ci * chunk:ci * chunk + size],
+                st.chunk_masses[0].block_raw(scheme, ci),
+                rng.random(s), scheme, st.z[scheme], engine.kappa,
+                st.n_total)
+            assert got.blocks == -(-size // 1024)
+            assert got.records == size
+
+
 @pytest.mark.parametrize("scheme", ["sqrt", "prop"])
 def test_hierarchical_draw_matches_dense_distribution(scheme):
     """Fixed-key statistical equivalence vs the dense-CDF path: the

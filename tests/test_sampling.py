@@ -87,10 +87,19 @@ def test_chunk_raw_masses_ignore_sentinels():
     rng = np.random.default_rng(7)
     scores = rng.random(5000).astype(np.float32)
     scores[::7] = -1.0                         # unscored sentinel
-    s_sqrt, s_a = sampling.chunk_raw_masses(scores)
+    s_sqrt, s_a, b_sqrt, b_a = sampling.chunk_raw_masses(scores)
     a = np.clip(scores, 0.0, 1.0)              # sentinel clips to 0 raw mass
     assert s_sqrt == pytest.approx(float(np.sum(np.sqrt(a), dtype=np.float64)))
     assert s_a == pytest.approx(float(np.sum(a, dtype=np.float64)))
+    # per 1,024-record block, the last one short (5000 = 4·1024 + 904)
+    starts = np.arange(0, 5000, sampling.BLOCK_RECORDS)
+    assert b_sqrt.size == b_a.size == 5
+    np.testing.assert_allclose(
+        b_sqrt, [np.sum(np.sqrt(a[i:i + 1024]), dtype=np.float64)
+                 for i in starts], rtol=1e-12)
+    np.testing.assert_allclose(
+        b_a, [np.sum(a[i:i + 1024], dtype=np.float64) for i in starts],
+        rtol=1e-12)
 
 
 def test_defensive_chunk_mass_is_sum_of_record_probs():

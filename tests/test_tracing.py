@@ -171,12 +171,22 @@ def test_span_arguments_are_the_engine_counts(traced):
         chunk = (idx - eng.offsets[shard]) // CHUNK
         fell_in = {(int(a), int(b)) for a, b in zip(shard, chunk)}
         total_chunks = eng.plan.total_chunks
+        # the distinct 1,024-record blocks hit, and the records they hold
+        block = (idx - eng.offsets[shard] - chunk * CHUNK) // 1024
+        hit = {(int(a), int(b), int(c))
+               for a, b, c in zip(shard, chunk, block)}
+        hit_records = sum(min(1024, eng.plan.shard_spans(a)[b].size - c * 1024)
+                          for a, b, c in hit)
     sample, = named(spans, "supg.sample")
     assert sample[3]["draws"] == BUDGET
     assert sample[3]["chunks"] == len(fell_in)
     resolved = named(spans, "supg.sample.chunk")
     assert {(sp[3]["shard"], sp[3]["chunk"]) for sp in resolved} == fell_in
     assert sum(sp[3]["draws"] for sp in resolved) == BUDGET
+    assert sum(sp[3]["blocks"] for sp in resolved) == len(hit)
+    assert sum(sp[3]["records"] for sp in resolved) == hit_records
+    assert hit_records <= min(BUDGET * 1024, RECORDS)
+    assert sample[3]["records"] == hit_records
 
     # Labels: the channel's own counters.
     wait, = named(spans, "supg.drain_wait")
