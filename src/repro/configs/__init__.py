@@ -15,6 +15,7 @@ _MODULES = {
     "smollm-360m": "repro.configs.smollm_360m",
     "deepseek-7b": "repro.configs.deepseek_7b",
     "deepseek-v2-236b": "repro.configs.deepseek_v2_236b",
+    "deepseek-v2-lite": "repro.configs.deepseek_v2_lite",
     "llama4-maverick-400b-a17b": "repro.configs.llama4_maverick_400b",
     "zamba2-1.2b": "repro.configs.zamba2_1_2b",
 }

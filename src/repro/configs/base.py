@@ -32,6 +32,13 @@ class ModelConfig:
     qkv_bias: bool = False          # qwen1.5
     qk_norm: bool = False           # chameleon
     rope_theta: float = 10_000.0
+    # YaRN rotary scaling (deepseek-v2's `rope_scaling`); factor 0 = none
+    yarn_factor: float = 0.0
+    yarn_original_max_positions: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # --- MLA (deepseek-v2) ---
     use_mla: bool = False
@@ -50,7 +57,12 @@ class ModelConfig:
     dense_d_ff: int = 0             # hidden dim of dense (non-MoE) layers
     first_k_dense: int = 0          # deepseek-v2: leading dense layers
     moe_layer_step: int = 1         # llama4: MoE every k-th layer
-    capacity_factor: float = 1.25
+    norm_topk_prob: bool = False    # renormalise the top-k softmax gates
+    routed_scaling_factor: float = 1.0
+    # The routed experts this device holds: [first_held_expert,
+    # first_held_expert + num_held_experts); 0 held = all num_experts.
+    first_held_expert: int = 0
+    num_held_experts: int = 0
     router_aux_coef: float = 0.001
 
     # --- SSM / linear attention ---
@@ -81,6 +93,11 @@ class ModelConfig:
         if self.head_dim == 0 and self.num_heads:
             object.__setattr__(self, "head_dim",
                                self.d_model // self.num_heads)
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights this device holds."""
+        return self.num_held_experts or self.num_experts
 
     @property
     def attention_free(self) -> bool:

@@ -2,7 +2,13 @@
 
 60L d_model=5120 128H (MLA kv_lora=512, rope=64, nope=128, v=128,
 q_lora=1536) moe_d_ff=1536, 2 shared + 160 routed top-6, first layer dense
-(dense d_ff=12288), vocab=102400 [arXiv:2405.04434; hf].
+(dense d_ff=12288), vocab=102400, softmax gates not renormalised
+(norm_topk_prob false) [arXiv:2405.04434; hf].
+
+Not implemented, so this router is not the published one: group-limited
+routing (8 expert groups, top 3 groups a token) and routed_scaling_factor
+16, which stays at 1 here. The published model's YaRN rope scaling is
+not set either.
 """
 from repro.configs.base import ModelConfig
 
@@ -14,7 +20,7 @@ CONFIG = ModelConfig(
     qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
     moe=True, num_experts=160, num_experts_per_tok=6,
     num_shared_experts=2, moe_d_ff=1536, dense_d_ff=12288, first_k_dense=1,
-    remat="block",
+    norm_topk_prob=False, remat="block",
 )
 
 

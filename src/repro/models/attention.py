@@ -26,12 +26,14 @@ NEG_INF = -1e30
 # Blocked causal attention (exact, online softmax)
 # --------------------------------------------------------------------------
 
-def chunked_causal_attention(q, k, v, *, q_chunk=1024, kv_chunk=1024):
+def chunked_causal_attention(q, k, v, *, q_chunk=1024, kv_chunk=1024,
+                             scale=None):
     """q: (B,S,H,dh), k/v: (B,S,KV,dh) -> (B,S,H,dh). Causal, GQA-aware.
 
     Two-level lax.scan with online softmax: outer over query chunks, inner
     over kv chunks (only chunks at-or-before the query chunk contribute).
-    Exact — matches plain softmax attention to fp32 tolerance.
+    Exact — matches plain softmax attention to fp32 tolerance. `scale`
+    multiplies the scores (default 1/sqrt(dh)).
     """
     b, s, h, dh = q.shape
     kv = k.shape[2]
@@ -45,7 +47,8 @@ def chunked_causal_attention(q, k, v, *, q_chunk=1024, kv_chunk=1024):
     qc = q.reshape(b, nq, q_chunk, kv, g, dh)
     kc = k.reshape(b, nk, kv_chunk, kv, dh)
     vc = v.reshape(b, nk, kv_chunk, kv, dv)
-    scale = 1.0 / jnp.sqrt(jnp.float32(dh))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.float32(dh))
 
     def outer(_, qi):
         qblk, qidx = qi                      # (b, qc, kv, g, dh), scalar
@@ -91,7 +94,7 @@ def chunked_causal_attention(q, k, v, *, q_chunk=1024, kv_chunk=1024):
 
 
 def context_parallel_attention(q, k, v, *, m_size, q_chunk=None,
-                               kv_chunk=1024):
+                               kv_chunk=1024, scale=None):
     """Causal attention with the query-chunk axis BATCHED (not scanned) and
     sharded over the "model" mesh axis — context parallelism.
 
@@ -124,7 +127,8 @@ def context_parallel_attention(q, k, v, *, m_size, q_chunk=None,
             qc, P(u, "model", u, u, u, u))
     kc = k.reshape(b, nk, kv_chunk, kv, dh)
     vc = v.reshape(b, nk, kv_chunk, kv, dv)
-    scale = 1.0 / jnp.sqrt(jnp.float32(dh))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.float32(dh))
     q_pos = (jnp.arange(nq)[:, None] * q_chunk
              + jnp.arange(q_chunk)[None, :])          # (nq, qc)
 
@@ -159,7 +163,7 @@ def context_parallel_attention(q, k, v, *, m_size, q_chunk=None,
         q.dtype)
 
 
-def _attention_dispatch(cfg, q, k, v, q_chunk, kv_chunk):
+def _attention_dispatch(cfg, q, k, v, q_chunk, kv_chunk, scale=None):
     """Pick scanned (memory-lean default) vs context-parallel (production
     mesh) blocked attention."""
     if cfg.shard_activations:
@@ -172,9 +176,9 @@ def _attention_dispatch(cfg, q, k, v, q_chunk, kv_chunk):
                 # probe mode: loop-free (kv unchunked) so costs are counted
                 kvc = s if cfg.unroll_layers else kv_chunk
                 return context_parallel_attention(q, k, v, m_size=m_size,
-                                                  kv_chunk=kvc)
+                                                  kv_chunk=kvc, scale=scale)
     return chunked_causal_attention(q, k, v, q_chunk=q_chunk,
-                                    kv_chunk=kv_chunk)
+                                    kv_chunk=kv_chunk, scale=scale)
 
 
 def decode_attention(q, cache_k, cache_v, pos):
@@ -235,8 +239,8 @@ def _gqa_qkv(p, cfg, x, positions):
     if cfg.qk_norm:
         q = layers.rms_norm(p["q_norm"], q, cfg.norm_eps)
         k = layers.rms_norm(p["k_norm"], k, cfg.norm_eps)
-    q = layers.apply_rope(q, positions, cfg.rope_theta)
-    k = layers.apply_rope(k, positions, cfg.rope_theta)
+    q = layers.apply_rope(q, positions, cfg)
+    k = layers.apply_rope(k, positions, cfg)
     return q, k, v
 
 
@@ -304,7 +308,7 @@ def _mla_q(p, cfg, x, positions):
         q = matmul(x, p["wq"])
     q = q.reshape(b, s, h, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = layers.apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = layers.apply_rope(q_rope, positions, cfg)
     return q_nope, q_rope
 
 
@@ -313,25 +317,34 @@ def _mla_latent(p, cfg, x, positions):
     c = layers.rms_norm(p["kv_norm"], dkv[..., :cfg.kv_lora_rank],
                         cfg.norm_eps)
     k_rope = dkv[..., cfg.kv_lora_rank:][..., None, :]   # shared single head
-    k_rope = layers.apply_rope(k_rope, positions, cfg.rope_theta)[..., 0, :]
+    k_rope = layers.apply_rope(k_rope, positions, cfg)[..., 0, :]
     return c, k_rope
+
+
+def _mla_softmax_scale(cfg):
+    """1/sqrt(nope + rope head dims), times YaRN's mscale squared."""
+    return (layers.yarn_softmax_scale(cfg)
+            / float(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** 0.5)
 
 
 def mla_prefill(p, cfg, x, positions, q_chunk=1024, kv_chunk=1024):
     """Materialized (training-style) MLA attention."""
-    b, s, _ = x.shape
-    h = cfg.num_heads
-    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    q_nope, q_rope = _mla_q(p, cfg, x, positions)
-    c, k_rope = _mla_latent(p, cfg, x, positions)
-    k_nope = matmul(c, p["w_uk"]).reshape(b, s, h, dn)
-    v = matmul(c, p["w_uv"]).reshape(b, s, h, dv)
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (b, s, h, dr))],
-        axis=-1)
-    o = _attention_dispatch(cfg, q, k, v, q_chunk, kv_chunk)
-    return matmul_rowparallel(o.reshape(b, s, -1), p["wo"], cfg)
+    with jax.named_scope("supg.mla"):
+        b, s, _ = x.shape
+        h = cfg.num_heads
+        dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        dv = cfg.v_head_dim
+        q_nope, q_rope = _mla_q(p, cfg, x, positions)
+        c, k_rope = _mla_latent(p, cfg, x, positions)
+        k_nope = matmul(c, p["w_uk"]).reshape(b, s, h, dn)
+        v = matmul(c, p["w_uv"]).reshape(b, s, h, dv)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (b, s, h, dr))],
+            axis=-1)
+        o = _attention_dispatch(cfg, q, k, v, q_chunk, kv_chunk,
+                                scale=_mla_softmax_scale(cfg))
+        return matmul_rowparallel(o.reshape(b, s, -1), p["wo"], cfg)
 
 
 def mla_decode(p, cfg, x, cache, pos):
@@ -362,8 +375,7 @@ def mla_decode(p, cfg, x, cache, pos):
     s_rope = jnp.einsum("bhd,bsd->bhs", q_rope[:, 0].astype(jnp.float32),
                         cache_kr.astype(jnp.float32),
                         preferred_element_type=jnp.float32)
-    scale = 1.0 / jnp.sqrt(jnp.float32(dn + dr))
-    scores = (s_lat + s_rope) * scale
+    scores = (s_lat + s_rope) * _mla_softmax_scale(cfg)
     valid = jnp.arange(cache_c.shape[1])[None] <= pos[:, None]
     scores = jnp.where(valid[:, None], scores, NEG_INF)
     pattn = jax.nn.softmax(scores, axis=-1)

@@ -10,6 +10,8 @@ softmax always run in fp32.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -124,18 +126,63 @@ def rope_frequencies(head_dim, theta):
     return 1.0 / (theta ** exponents)  # (head_dim/2,)
 
 
-def rope_angles(positions, head_dim, theta):
-    """positions: (...,) int -> (..., head_dim/2) angles, fp32."""
-    freqs = jnp.asarray(rope_frequencies(head_dim, theta))
-    return positions.astype(jnp.float32)[..., None] * freqs
+def yarn_mscale(factor, mscale=1.0):
+    """YaRN's attention temperature (DeepSeek-V2's `yarn_get_mscale`)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x, positions, theta):
-    """x: (..., seq, heads, head_dim); positions: (..., seq)."""
+def yarn_frequencies(head_dim, theta, factor, original_max, beta_fast,
+                     beta_slow):
+    """YaRN's inverse frequencies, as DeepSeek-V2's
+    `DeepseekV2YarnRotaryEmbedding` blends them: dims that turn more than
+    `beta_fast` times over the original context keep theta's frequency,
+    dims that turn fewer than `beta_slow` times are divided by `factor`,
+    and a linear ramp joins the two."""
+    def correction_dim(rotations):
+        return (head_dim * math.log(original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    extra = rope_frequencies(head_dim, theta)
+    return (extra / factor * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def rope_table(cfg, head_dim):
+    """(inverse frequencies (head_dim/2,), cos/sin scale) of cfg's rotary
+    embedding: plain theta, or YaRN where `cfg.yarn_factor` is set."""
+    if not cfg.yarn_factor:
+        return rope_frequencies(head_dim, cfg.rope_theta), 1.0
+    inv_freq = yarn_frequencies(
+        head_dim, cfg.rope_theta, cfg.yarn_factor,
+        cfg.yarn_original_max_positions, cfg.yarn_beta_fast,
+        cfg.yarn_beta_slow)
+    scale = (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+             / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+    return inv_freq, scale
+
+
+def yarn_softmax_scale(cfg):
+    """YaRN's factor on the attention softmax scale: mscale(factor,
+    mscale_all_dim) squared, 1 without YaRN."""
+    if not (cfg.yarn_factor and cfg.yarn_mscale_all_dim):
+        return 1.0
+    return yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+
+
+def apply_rope(x, positions, cfg):
+    """x: (..., seq, heads, head_dim); positions: (..., seq). Rotates the
+    (first half, second half) pairs of the last axis."""
     half = x.shape[-1] // 2
-    ang = rope_angles(positions, x.shape[-1], theta)  # (..., seq, half)
+    inv_freq, scale = rope_table(cfg, x.shape[-1])
+    ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq)
     cos = jnp.cos(ang)[..., None, :]
     sin = jnp.sin(ang)[..., None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half], x[..., half:]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     out = jnp.concatenate(

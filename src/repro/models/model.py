@@ -2,6 +2,7 @@
 
     params = init(key, cfg)
     logits, aux = apply_train(params, cfg, tokens)          (B,S,V) or (B,S,K,V)
+    logits, aux = last_logits(params, cfg, tokens)          (B,V) or (B,K,V)
     scores      = proxy_scores(params, cfg, tokens, target) (B,) in [0,1]
     logits, caches = apply_decode(params, cfg, tokens, caches, pos)
     caches      = init_caches(cfg, batch, seq_len)
@@ -86,8 +87,8 @@ def _constrain_vocab(cfg, logits):
     return jax.lax.with_sharding_constraint(logits, spec)
 
 
-def apply_train(params, cfg, tokens, q_chunk=1024, kv_chunk=1024):
-    """Training/prefill logits over the full sequence."""
+def _body(params, cfg, tokens, q_chunk, kv_chunk):
+    """Embedding and layers: (hidden states before the final norm, aux)."""
     b = tokens.shape[0]
     s = tokens.shape[1]
     if cfg.unroll_layers:
@@ -96,10 +97,24 @@ def apply_train(params, cfg, tokens, q_chunk=1024, kv_chunk=1024):
         q_chunk = kv_chunk = s
     x = _embed(params, cfg, tokens)
     positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-    x, aux = transformer.body_prefill(params["body"], cfg, x, positions,
-                                      q_chunk=q_chunk, kv_chunk=kv_chunk)
+    return transformer.body_prefill(params["body"], cfg, x, positions,
+                                    q_chunk=q_chunk, kv_chunk=kv_chunk)
+
+
+def apply_train(params, cfg, tokens, q_chunk=1024, kv_chunk=1024):
+    """Training/prefill logits over the full sequence, and the body's aux
+    (`transformer.body_prefill`)."""
+    x, aux = _body(params, cfg, tokens, q_chunk, kv_chunk)
     x = layers.rms_norm(params["ln_f"], x, cfg.norm_eps)
     return _constrain_vocab(cfg, _head(params, cfg, x)), aux
+
+
+def last_logits(params, cfg, tokens):
+    """Logits at the last position only, and the body's aux: the head is
+    applied to one position a record."""
+    x, aux = _body(params, cfg, tokens, 1024, 1024)
+    x = layers.rms_norm(params["ln_f"], x[:, -1:], cfg.norm_eps)
+    return _head(params, cfg, x)[:, 0], aux
 
 
 def loss_fn(params, cfg, tokens, labels, mask=None):
@@ -109,13 +124,12 @@ def loss_fn(params, cfg, tokens, labels, mask=None):
             logits.reshape(-1, cfg.vocab_size), labels.reshape(-1))
     else:
         ce = layers.softmax_cross_entropy(logits, labels, mask)
-    return ce + aux, (ce, aux)
+    return ce + aux["loss"], (ce, aux["loss"])
 
 
 def proxy_scores(params, cfg, tokens, target_token=1):
     """A(x) in [0,1]: probability of the predicate token at the last step."""
-    logits, _ = apply_train(params, cfg, tokens)
-    last = logits[:, -1]
+    last, _ = last_logits(params, cfg, tokens)
     if cfg.num_codebooks > 1:
         last = last.mean(axis=1)
     p = jax.nn.softmax(last.astype(jnp.float32), axis=-1)

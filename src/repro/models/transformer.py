@@ -78,12 +78,9 @@ def attn_block_prefill(p, cfg, x, positions, ffn="mlp", gate_fn="softmax",
                     q_chunk=q_chunk, kv_chunk=kv_chunk)
     xn = layers.rms_norm(p["ln2"], x, cfg.norm_eps)
     if ffn == "mlp":
-        x = x + layers.mlp(p["mlp"], xn, cfg.act, cfg)
-        aux = jnp.float32(0.0)
-    else:
-        h, aux = moe.moe_apply(p["moe"], cfg, xn, gate_fn)
-        x = x + h
-    return x, aux
+        return x + layers.mlp(p["mlp"], xn, cfg.act, cfg), None
+    h, aux = moe.moe_apply(p["moe"], cfg, xn, gate_fn)
+    return x + h, aux
 
 
 def attn_block_decode(p, cfg, x, cache, pos, ffn="mlp", gate_fn="softmax"):
@@ -157,8 +154,13 @@ def _init_zamba_body(key, cfg):
 # ---- prefill / train forward ----------------------------------------------
 
 def body_prefill(params, cfg, x, positions, q_chunk=1024, kv_chunk=1024):
-    """x: (B,S,d) -> (B,S,d), aux_loss. Scan-over-layers everywhere."""
+    """x: (B,S,d) -> (B,S,d), aux. Scan-over-layers everywhere.
+
+    aux["loss"] is the router load-balancing loss (0 without MoE); MoE
+    archs add `held_assignments` and `largest_held_group`, one entry per
+    MoE layer (`moe.moe_apply`)."""
     aux_total = jnp.float32(0.0)
+    no_moe = {"loss": aux_total}
     gate_fn = "sigmoid" if cfg.moe_layer_step > 1 else "softmax"
 
     if cfg.block == "rwkv":
@@ -168,7 +170,7 @@ def body_prefill(params, cfg, x, positions, q_chunk=1024, kv_chunk=1024):
             out, _ = rwkv.rwkv_block(blk, cfg, h, state)
             return out, None
         x, _ = _scan_layers(body, x, params["blocks"], cfg)
-        return x, aux_total
+        return x, no_moe
 
     if cfg.block == "mamba":
         return _zamba_prefill(params, cfg, x, positions, q_chunk, kv_chunk)
@@ -181,11 +183,12 @@ def body_prefill(params, cfg, x, positions, q_chunk=1024, kv_chunk=1024):
                                       q_chunk=q_chunk, kv_chunk=kv_chunk)
             h, a = attn_block_prefill(moe_p, cfg, h, positions, "moe",
                                       gate_fn, q_chunk, kv_chunk)
-            return (h, aux + a), None
-        (x, aux_total), _ = _scan_layers(
+            loss = a.pop("loss")
+            return (h, aux + loss), a
+        (x, aux_total), counts = _scan_layers(
             pair_body, (x, aux_total),
             (params["pairs_dense"], params["pairs_moe"]), cfg)
-        return x, aux_total
+        return x, {"loss": aux_total, **counts}
 
     if cfg.moe:
         def dense_body(carry, blk):
@@ -198,17 +201,18 @@ def body_prefill(params, cfg, x, positions, q_chunk=1024, kv_chunk=1024):
             h, aux = carry
             h, a = attn_block_prefill(blk, cfg, h, positions, "moe",
                                       gate_fn, q_chunk, kv_chunk)
-            return (h, aux + a), None
-        (x, aux_total), _ = _scan_layers(
+            loss = a.pop("loss")
+            return (h, aux + loss), a
+        (x, aux_total), counts = _scan_layers(
             moe_body, (x, aux_total), params["moe_blocks"], cfg)
-        return x, aux_total
+        return x, {"loss": aux_total, **counts}
 
     def body(h, blk):
         out, _ = attn_block_prefill(blk, cfg, h, positions, "mlp",
                                     q_chunk=q_chunk, kv_chunk=kv_chunk)
         return out, None
     x, _ = _scan_layers(body, x, params["blocks"], cfg)
-    return x, aux_total
+    return x, no_moe
 
 
 def _zamba_prefill(params, cfg, x, positions, q_chunk, kv_chunk):
@@ -231,7 +235,7 @@ def _zamba_prefill(params, cfg, x, positions, q_chunk, kv_chunk):
             out, _ = mamba.mamba_block(blk, cfg, h, state)
             return out, None
         x, _ = _scan_layers(tail_body, x, params["mamba_tail"], cfg)
-    return x, jnp.float32(0.0)
+    return x, {"loss": jnp.float32(0.0)}
 
 
 # ---- decode forward --------------------------------------------------------

@@ -6,6 +6,9 @@ import pytest
 
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.linear_scan import ops as ls_ops
+from repro.kernels.moe_gmm import moe_gmm as gmm_kernel
+from repro.kernels.moe_gmm import ops as gmm_ops
+from repro.kernels.moe_gmm import ref as gmm_ref
 from repro.kernels.score_hist import ops as sh_ops
 from repro.kernels.threshold_select import ops as ts_ops
 from repro.kernels.threshold_select import ref as ts_ref
@@ -191,3 +194,54 @@ def test_threshold_select_memmap_chunk(tmp_path):
     out = ts_ops.threshold_select(arr[1000:3000], 0.7, backend="ref")
     np.testing.assert_array_equal(
         out, np.nonzero(np.asarray(arr[1000:3000]) >= 0.7)[0])
+
+
+# Group sizes with empty groups, groups that are no multiple of the row
+# tile (16 here) and groups that share a tile; the last case leaves rows
+# past the last group.
+GMM_GROUPS = [[5, 0, 17, 3, 39], [0, 0, 64, 0], [16, 16, 32, 0],
+              [1, 2, 3, 4, 5, 6, 7], [0, 0, 0]]
+
+
+@pytest.mark.parametrize("sizes", GMM_GROUPS)
+@pytest.mark.parametrize("k,n", [(32, 48), (256, 384)])
+def test_moe_gmm_matches_ragged_dot_and_the_loop(sizes, k, n):
+    """Pallas (interpret) and ragged_dot against the loop over groups, on
+    the grouped rows. Tolerance: float32 products summed in another
+    order, k terms of unit-variance operands."""
+    m = 64
+    rng = np.random.default_rng(len(sizes) * k)
+    lhs = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
+    rhs = jnp.asarray(rng.normal(size=(len(sizes), k, n)), jnp.float32)
+    gs = jnp.asarray(sizes, jnp.int32)
+    rows = sum(sizes)
+    want = gmm_ref.gmm_ref(lhs, rhs, sizes)[:rows]
+    pallas = np.asarray(gmm_kernel.gmm(lhs, rhs, gs, tm=16, interpret=True))
+    ragged = np.asarray(gmm_ops.moe_gmm(lhs, rhs, gs))
+    np.testing.assert_allclose(pallas[:rows], want, atol=1e-4 * k ** 0.5)
+    np.testing.assert_allclose(ragged[:rows], want, atol=1e-4 * k ** 0.5)
+
+
+def test_moe_gmm_tiles_divide_the_published_widths():
+    """V2-Lite's gate-up (2048 -> 2 x 1408) and down (1408 -> 2048)."""
+    assert [gmm_kernel.tile(d) for d in (2048, 2816, 1408)] == [
+        1024, 1408, 1408]
+    assert gmm_kernel.tile(96) == 96          # no multiple of 128: one block
+
+
+def test_moe_gmm_gradient_is_ragged_dots():
+    """The custom VJP gives ragged_dot's gradients for lhs and rhs."""
+    rng = np.random.default_rng(3)
+    lhs = jnp.asarray(rng.normal(size=(40, 24)), jnp.float32)
+    rhs = jnp.asarray(rng.normal(size=(3, 24, 8)), jnp.float32)
+    gs = jnp.asarray([10, 0, 25], jnp.int32)
+    ct = jnp.asarray(rng.normal(size=(40, 8)), jnp.float32).at[35:].set(0)
+
+    def loss(f):
+        return lambda a, b: jnp.sum(f(a, b) * ct)
+    got = jax.grad(loss(lambda a, b: gmm_ops.moe_gmm(a, b, gs)),
+                   argnums=(0, 1))(lhs, rhs)
+    want = jax.grad(loss(lambda a, b: jax.lax.ragged_dot(a, b, gs)),
+                    argnums=(0, 1))(lhs, rhs)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-5)

@@ -9,6 +9,7 @@ touches the TPU library while a module is imported. The persistent
 compilation cache is off around these compiles, since an entry written for
 a described chip cannot be read back without one.
 """
+import dataclasses
 import importlib.util
 import json
 import os
@@ -21,8 +22,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs import smollm_360m
+from repro.configs import deepseek_v2_lite, smollm_360m
 from repro.data.pipeline import CHUNK_RECORDS
+from repro.kernels.moe_gmm.moe_gmm import gmm
 from repro.kernels.score_hist.score_hist import score_hist
 from repro.kernels.threshold_select.threshold_select import (
     threshold_select_rows)
@@ -90,6 +92,41 @@ def test_smollm_prefill_compiles_for_v5e(one_chip):
     compiled = jax.jit(servelib.make_serve_prefill(cfg)).lower(
         params, batch).compile()
     assert compiled.out_info.shape == (64,)            # one score a record
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < V5E_HBM_BYTES
+
+
+# V2-Lite's held-expert projections at one token block of the cell:
+# 16,384 tokens x 6 assignments, gate-up then down.
+@pytest.mark.parametrize("k,n", [(2048, 2 * 1408), (1408, 2048)])
+def test_moe_gmm_compiles_for_v5e(one_chip, k, n):
+    rows = 16384 * 6
+    lhs = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
+    rhs = jax.ShapeDtypeStruct((8, k, n), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    compiled = gmm.lower(lhs, rhs, sizes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.out_info.shape == (rows, n)
+
+
+def test_dsv2_lite_prefill_compiles_for_v5e(one_chip, monkeypatch):
+    """The ingest cell's program: V2-Lite holding 8 of 64 experts, batch
+    128 of 512 tokens, with the grouped matmul on its TPU path (the
+    platform check is steered here: without a chip the backend is the
+    CPU)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(deepseek_v2_lite.CONFIG, num_held_experts=8)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda k: model.init(k, cfg), jax.random.PRNGKey(0)))
+    batch = {"tokens": jax.ShapeDtypeStruct((128, 512), jnp.int32,
+                                            sharding=one_chip)}
+    compiled = jax.jit(servelib.make_serve_prefill(cfg)).lower(
+        params, batch).compile()
+    assert compiled.out_info.shape == (128,)
+    text = compiled.as_text()
+    assert "%moe_gmm" in text and "ragged-dot" not in text
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < V5E_HBM_BYTES
