@@ -16,8 +16,10 @@ expert gets its row in expert order (the expert's start plus its rank
 among that expert's assignments), the token rows are gathered into that
 order, the held experts' SwiGLU runs on their ragged row groups through
 the grouped matmul (`kernels/moe_gmm`: Pallas on TPU, `ragged_dot`
-elsewhere), and the combine gathers each assignment's row back and sums
-the k of a token with their gates. FLOPs are the active N·k·held/E·d·ff
+elsewhere), and the combine sums each token's held rows with their
+gates (`kernels/moe_combine`: on TPU a Pallas kernel that reads each token
+tile's rows from every held expert's contiguous range, the slot-major jnp
+gather elsewhere). FLOPs are the active N·k·held/E·d·ff
 (plus the router); the static row buffer is the worst case, N·min(k,
 held) rows, and a batch whose buffer would pass ROWS_MAX rows runs in
 token blocks.
@@ -29,6 +31,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.moe_combine.ops import moe_combine
 from repro.kernels.moe_gmm.ops import moe_gmm
 from repro.models import layers
 from repro.models.layers import dense_init
@@ -157,7 +160,7 @@ def _token_block(n, rows_per_token):
 def _held_block(xt, expert_ids, gate_vals, w_gate_up, wd, first):
     """One token block through the held experts: (out (n, d) fp32, the
     assignments each held expert took (held,))."""
-    n, d = xt.shape
+    n = xt.shape[0]
     k = expert_ids.shape[1]
     held = wd.shape[0]
     rows = n * min(k, held)
@@ -179,9 +182,7 @@ def _held_block(xt, expert_ids, gate_vals, w_gate_up, wd, first):
     h = jax.nn.silu(g.astype(jnp.float32)).astype(xt.dtype) * u
     y = moe_gmm(h, wd, sizes)
     with jax.named_scope("supg.moe.combine"):
-        ya = jnp.take(y, pos, axis=0, mode="fill", fill_value=0)
-        out = jnp.einsum("nkd,nk->nd", ya.reshape(n, k, d).astype(
-            jnp.float32), gate_vals)
+        out = moe_combine(y, pos.reshape(n, k), gate_vals, row_token, sizes)
     return out, sizes
 
 

@@ -6,6 +6,8 @@ import pytest
 
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.linear_scan import ops as ls_ops
+from repro.kernels.moe_combine import moe_combine as combine_kernel
+from repro.kernels.moe_combine import ops as combine_ops
 from repro.kernels.moe_gmm import moe_gmm as gmm_kernel
 from repro.kernels.moe_gmm import ops as gmm_ops
 from repro.kernels.moe_gmm import ref as gmm_ref
@@ -245,3 +247,106 @@ def test_moe_gmm_gradient_is_ragged_dots():
                     argnums=(0, 1))(lhs, rhs)
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-5)
+
+
+# Routings of 64 tokens over 16 experts, 4 of them held from `first`,
+# combined in tiles of 16 tokens and windows of 8 rows: (k, first, how).
+# "all_held" is the worst case, every range a whole tile of 16 rows;
+# "over_a_window" sends every token to the first held expert, 16 rows a
+# tile against a window of 8; "top1" is Maverick's top-1.
+COMBINE_CASES = {"random": (4, 0, "random"), "all_held": (4, 0, "held"),
+                 "none_held": (4, 0, "none"),
+                 "over_a_window": (4, 0, "first"),
+                 "first_nonzero": (4, 8, "random"),
+                 "top1": (1, 0, "random")}
+COMBINE_TOKENS, COMBINE_EXPERTS, COMBINE_HELD = 64, 16, 4
+
+
+def _routing(k, first, how, rng):
+    held = np.arange(first, first + COMBINE_HELD)
+    rest = np.setdiff1d(np.arange(COMBINE_EXPERTS), held)
+    pick = {"random": lambda: rng.permutation(COMBINE_EXPERTS)[:k],
+            "held": lambda: rng.permutation(held)[:k],
+            "none": lambda: rng.permutation(rest)[:k],
+            "first": lambda: np.r_[first, rng.permutation(rest)[:k - 1]]}
+    return np.stack([pick[how]() for _ in range(COMBINE_TOKENS)])
+
+
+def _counting_sort(ids, first):
+    """The dispatch's rows, plainly: each held expert's assignments in
+    token order, one expert after another; (pos, row_token, sizes)."""
+    n, k = ids.shape
+    rows = n * min(k, COMBINE_HELD)
+    pos = np.full((n, k), rows, np.int32)
+    row_token = np.zeros(rows, np.int32)
+    sizes = np.zeros(COMBINE_HELD, np.int32)
+    r = 0
+    for e in range(COMBINE_HELD):
+        for t, j in zip(*np.nonzero(ids == first + e)):
+            pos[t, j], row_token[r] = r, t
+            sizes[e] += 1
+            r += 1
+    return pos, row_token, sizes
+
+
+def _combine_inputs(case, d=128):
+    k, first, how = COMBINE_CASES[case]
+    rng = np.random.default_rng(sorted(COMBINE_CASES).index(case))
+    pos, row_token, sizes = _counting_sort(_routing(k, first, how, rng),
+                                           first)
+    y = rng.normal(size=(pos.shape[0] * min(k, COMBINE_HELD), d))
+    y[sizes.sum():] = np.nan              # rows the grouped matmul leaves
+    gates = rng.random(pos.shape)
+    return (jnp.asarray(y, jnp.bfloat16), jnp.asarray(pos),
+            jnp.asarray(gates, jnp.float32), jnp.asarray(row_token),
+            jnp.asarray(sizes))
+
+
+@pytest.mark.parametrize("case", sorted(COMBINE_CASES))
+def test_moe_combine_matches_the_slot_loop(case):
+    """Pallas (interpret) and the slot-major jnp combine against a loop
+    over each token's k slots; rows past the groups hold NaN and are never
+    read. Tolerance: float32 products of bf16 rows, at most k summed."""
+    y, pos, gates, row_token, sizes = _combine_inputs(case)
+    y64, g64 = np.asarray(y, np.float64), np.asarray(gates, np.float64)
+    want = np.zeros((pos.shape[0], y.shape[1]))
+    for t, j in np.ndindex(*pos.shape):
+        if pos[t, j] < y.shape[0]:
+            want[t] += g64[t, j] * y64[pos[t, j]]
+    pallas = np.asarray(combine_kernel.combine(
+        y, pos, gates, row_token, sizes, tt=16, tr=8, interpret=True))
+    jnp_path = np.asarray(combine_ops.moe_combine(y, pos, gates, row_token,
+                                                  sizes))
+    for got in (pallas, jnp_path):
+        assert got.dtype == np.float32 and np.isfinite(got).all()
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+def test_moe_combine_tiles_fit_the_published_widths():
+    """Token tiles of V2-Lite's block (d 2,048) and Maverick's (d 5,120)
+    keep the float32 output tile within 4 MiB; a token count no multiple
+    of 8 divides is one tile."""
+    assert combine_kernel.tile_t(16384, 2048) == 512
+    assert combine_kernel.tile_t(16384, 5120) == 128
+    assert combine_kernel.tile_t(24, 2048) == 8
+    assert combine_kernel.tile_t(6, 2048) == 6
+
+
+def test_moe_combine_gradient_is_the_slot_loops():
+    """The custom VJP gives the jnp combine's gradients: each held row
+    gets its token's cotangent times the gate, each gate the cotangent's
+    product with its row."""
+    y, pos, gates, row_token, sizes = _combine_inputs("random", d=16)
+    y = jnp.nan_to_num(y.astype(jnp.float32))
+    ct = jnp.asarray(np.random.default_rng(3).normal(size=(64, 16)),
+                     jnp.float32)
+    got_y, got_g = jax.grad(
+        lambda a, b: jnp.sum(combine_ops.moe_combine(
+            a, pos, b, row_token, sizes) * ct), argnums=(0, 1))(y, gates)
+    want_y, want_g = np.zeros(y.shape), np.zeros(gates.shape)
+    for t, j in np.ndindex(*pos.shape):
+        if pos[t, j] < y.shape[0]:
+            want_y[pos[t, j]] += gates[t, j] * ct[t]
+            want_g[t, j] = np.dot(ct[t], y[pos[t, j]])
+    np.testing.assert_allclose(np.asarray(got_y), want_y, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got_g), want_g, atol=1e-5)

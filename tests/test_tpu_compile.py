@@ -24,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import deepseek_v2_lite, smollm_360m
 from repro.data.pipeline import CHUNK_RECORDS
+from repro.kernels.moe_combine.moe_combine import combine, tile_t
 from repro.kernels.moe_gmm.moe_gmm import gmm
 from repro.kernels.score_hist.score_hist import score_hist
 from repro.kernels.threshold_select.threshold_select import (
@@ -110,11 +111,29 @@ def test_moe_gmm_compiles_for_v5e(one_chip, k, n):
     assert compiled.out_info.shape == (rows, n)
 
 
+def test_moe_combine_compiles_for_v5e(one_chip):
+    """The combine of one token block of the cell, 16,384 tokens x 6
+    slots over 8 held experts' rows: its temporaries stay far below the
+    (n·k, d) bf16 buffer, 402,653,184 bytes, that no combine may write."""
+    n, k, d = 16384, 6, 2048
+    rows = n * k
+    y = jax.ShapeDtypeStruct((rows, d), jnp.bfloat16, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((n, k), jnp.int32, sharding=one_chip)
+    gates = jax.ShapeDtypeStruct((n, k), jnp.float32, sharding=one_chip)
+    row_token = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    compiled = combine.lower(y, pos, gates, row_token, sizes,
+                             tt=tile_t(n, d)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.out_info.shape == (n, d)
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * d * 2
+
+
 def test_dsv2_lite_prefill_compiles_for_v5e(one_chip, monkeypatch):
     """The ingest cell's program: V2-Lite holding 8 of 64 experts, batch
-    128 of 512 tokens, with the grouped matmul on its TPU path (the
-    platform check is steered here: without a chip the backend is the
-    CPU)."""
+    128 of 512 tokens, with the grouped matmul and the combine on their
+    TPU paths (the platform check is steered here: without a chip the
+    backend is the CPU)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = dataclasses.replace(deepseek_v2_lite.CONFIG, num_held_experts=8)
     params = jax.tree.map(
@@ -127,6 +146,7 @@ def test_dsv2_lite_prefill_compiles_for_v5e(one_chip, monkeypatch):
     assert compiled.out_info.shape == (128,)
     text = compiled.as_text()
     assert "%moe_gmm" in text and "ragged-dot" not in text
+    assert "%moe_combine" in text
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < V5E_HBM_BYTES
